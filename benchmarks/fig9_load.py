@@ -32,6 +32,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
 from repro.serve import QueueFull, ReplicaRouter, Request, ServeEngine
 from repro.serve.kv_traffic import collective_traffic, kv_row_bytes
@@ -123,7 +124,7 @@ def build_router(cfg, params, *, replicas: int, chunk: int = 2):
     n_dev = jax.device_count()
     tp = n_dev if (cfg.n_kv_heads % n_dev == 0
                    and cfg.n_heads % n_dev == 0) else 1
-    mesh = jax.make_mesh((1, tp), ("data", "model")) if tp > 1 else None
+    mesh = make_test_mesh((1, tp)) if tp > 1 else None
     engines = [ServeEngine(cfg, params, max_slots=SLOTS, max_len=MAX_LEN,
                            chunk=chunk, mesh=mesh)
                for _ in range(replicas)]

@@ -161,6 +161,20 @@ def _warn_degraded_once(tasks, reports) -> None:
         RuntimeWarning, stacklevel=3)
 
 
+def _accelerator_live() -> bool:
+    """True once this process has brought up a non-CPU JAX backend.
+
+    A forked child of a process that holds an accelerator inherits its
+    runtime threads and device handles; the fan-out must not fork then.
+    Asks without initializing any backend.
+    """
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def compare(hlo_text: str, machines=None, n_devices: int = 1,
             max_workers: int | None = None, parallel: str = "auto",
             backends=None) -> dict:
@@ -184,8 +198,10 @@ def compare(hlo_text: str, machines=None, n_devices: int = 1,
     pool. `parallel`: "auto" (pool when the estimated analysis work
     amortizes the fork/IPC overhead, fork is available, and the models
     pickle), "serial" (in-process loop), or "process" (force the pool).
-    Ad-hoc unpicklable models and pool failures degrade to the serial
-    loop, so results never depend on the execution mode. Missing µ-op
+    A process that holds an accelerator never forks: there every mode
+    runs the serial loop. Ad-hoc unpicklable models and pool failures
+    degrade to the serial loop, so results never depend on the
+    execution mode. Missing µ-op
     classes warn once here in the parent, not once per worker.
     """
     if machines is None:
@@ -230,9 +246,9 @@ def compare(hlo_text: str, machines=None, n_devices: int = 1,
     # ~17 µs/instr·machine analysis vs a few hundred ms of pool setup:
     # the pool only pays off when the serial fan-out is >~ 1 s of work
     big_enough = tr.n_ops() * len(tasks) > 50_000
-    use_pool = parallel == "process" or (
+    use_pool = not _accelerator_live() and (parallel == "process" or (
         parallel == "auto" and workers > 1 and big_enough
-        and "fork" in multiprocessing.get_all_start_methods())
+        and "fork" in multiprocessing.get_all_start_methods()))
     if use_pool:
         try:
             pickle.dumps((models, bobjs))
@@ -242,20 +258,14 @@ def compare(hlo_text: str, machines=None, n_devices: int = 1,
     if use_pool:
         try:
             ctx = multiprocessing.get_context("fork")
-            with warnings.catch_warnings():
-                # the workers never touch XLA; silence jax's blanket
-                # fork-after-threads warning for this short-lived pool
-                warnings.filterwarnings(
-                    "ignore", message=".*os.fork.*", category=RuntimeWarning)
-                with ProcessPoolExecutor(max_workers=workers,
-                                         mp_context=ctx,
-                                         initializer=_pool_init,
-                                         initargs=(hlo_text,)) as ex:
-                    chunk = max(1, len(tasks) // workers)
-                    reports = list(ex.map(
-                        _compare_worker,
-                        [m for m, _ in tasks], [b for _, b in tasks],
-                        [n_devices] * len(tasks), chunksize=chunk))
+            with ProcessPoolExecutor(max_workers=workers, mp_context=ctx,
+                                     initializer=_pool_init,
+                                     initargs=(hlo_text,)) as ex:
+                chunk = max(1, len(tasks) // workers)
+                reports = list(ex.map(
+                    _compare_worker,
+                    [m for m, _ in tasks], [b for _, b in tasks],
+                    [n_devices] * len(tasks), chunksize=chunk))
         except Exception:
             reports = None          # broken pool: serial fallback
     if reports is None:

@@ -13,17 +13,20 @@ boundaries. This kernel is the WA-evasion-spirited fix at decode scale
   slot's position (and, with a sliding window, wholly before it), so
   per-step work scales with cache *occupancy*, not horizon.
 * The online-softmax accumulators (m, l, acc) live in VMEM scratch and
-  never touch HBM; queries are a single token, so all GQA heads are
-  packed into one ``(Hkv·G, Dh)`` tile (``(Sq·Hkv·G, Dh)`` for short
-  multi-token tiles) instead of wasting a grid dimension on
-  sub-sublane head tiles.
+  never touch HBM. One grid step fetches a (bk, Hkv, Dh) block of every
+  KV head at once; the queries arrive packed per KV head outside the
+  kernel, ``(Hkv, Sq·G, Dh)``, so every in-kernel value is a 2-D tile
+  and Mosaic sees no reshape or transpose (it refuses them).
+* Dead blocks are also never fetched: their index maps clamp to a live
+  block, which the pipeline does not copy again.
 * Long caches shard over ``n_splits`` KV splits (flash-decoding): each
   split accumulates its own partial (m, l, acc) and a cross-split
   combine merges them outside the kernel.
 
 Grid: (batch, n_splits, kv_blocks_per_split), KV innermost. ``pos`` is
 scalar-prefetched so both the kernel and its masks see every slot's
-position before any block work is issued.
+position before any block work is issued. Under a mesh the kernels run
+per TP shard through ``shard_map`` (``ops._per_shard``).
 
 Tile sizes come from the MemTier-driven autotuner
 (``repro.kernels.tuning``), not constants; routing and CPU fallbacks
@@ -38,13 +41,13 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+#: grid (batch, split, kv block): every axis but the innermost one
+#: writes disjoint output blocks
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -52,9 +55,12 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    window):
     """One (batch, split, kv-block) grid step of split-KV flash decode.
 
-    Scratch carries the online-softmax state across the innermost
-    (kv-block) grid dimension; rows of the packed query tile are
-    ordered (Sq major, G minor) per kv head.
+    Mosaic gets no in-kernel reshape or transpose: ``q_ref`` holds the
+    query rows already packed per KV head outside the kernel, (Hkv,
+    Sq*G, Dh) with rows ordered (Sq major, G minor), and each head's
+    (bk, Dh) KV tile is a strided ref load ``k_ref[:, j, :]`` out of
+    the (bk, Hkv, Dh) block. Scratch carries the online-softmax state
+    per head across the innermost (kv-block) grid dimension.
     """
     b = pl.program_id(0)
     s = pl.program_id(1)
@@ -76,44 +82,90 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale        # (Sq, H, dh)
-        dh = q.shape[-1]
-        # pack to (hkv, Sq*g, dh): kv-head batched, (Sq, g) rows minor
-        qp = q.reshape(sq, hkv, g, dh).transpose(1, 0, 2, 3)
-        qp = qp.reshape(hkv, sq * g, dh)
-        k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (hkv,bk,dh)
-        v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        st = jax.lax.dot_general(qp, k, (((2,), (2,)), ((0,), (0,))),
-                                 preferred_element_type=jnp.float32)
-        k_pos = start + jax.lax.iota(jnp.int32, bk)     # (bk,)
-        # row j of the Sq tile queries absolute position pos_b + j
-        q_pos = pos_b + jax.lax.iota(jnp.int32, sq * g) // g
-        mask = k_pos[None, :] <= q_pos[:, None]
+        shape = (sq * g, bk)
+        k_pos = start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # row r of a head's tile queries absolute position pos_b + r // g
+        q_pos = pos_b
+        if sq > 1:
+            q_pos = q_pos + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // g
+        mask = k_pos <= q_pos
         if window is not None:
-            mask = jnp.logical_and(
-                mask, k_pos[None, :] > q_pos[:, None] - window)
-        st = jnp.where(mask[None], st, NEG_INF)         # (hkv,Sq*g,bk)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, st.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(st - m_new[..., None])
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+            mask = jnp.logical_and(mask, k_pos > q_pos - window)
+        for j in range(hkv):
+            q = q_ref[j].astype(jnp.float32) * scale    # (rows, dh)
+            k = k_ref[:, j, :].astype(jnp.float32)      # (bk, dh)
+            v = v_ref[:, j, :].astype(jnp.float32)
+            st = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            st = jnp.where(mask, st, NEG_INF)
+            m_prev = m_scr[j]                           # (rows, 1)
+            m_new = jnp.maximum(m_prev, st.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(st - m_new)
+            l_scr[j] = l_scr[j] * alpha + p.sum(axis=1, keepdims=True)
+            acc_scr[j] = acc_scr[j] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[j] = m_new
 
     @pl.when(ik == bps - 1)
     def _finalize():
-        dh = acc_scr.shape[-1]
-        # unpack (hkv, Sq*g, ·) back to (Sq, H, ·)
-        def unpack(x, trail):
-            y = x.reshape((hkv, sq, g) + trail)
-            return y.transpose((1, 0, 2) + tuple(
-                3 + i for i in range(len(trail))))
-        o_ref[0, 0] = unpack(acc_scr[...], (dh,)).reshape(sq, hkv * g, dh)
-        m_ref[0, 0] = unpack(m_scr[...], ()).reshape(sq, hkv * g)
-        l_ref[0, 0] = unpack(l_scr[...], ()).reshape(sq, hkv * g)
+        o_ref[...] = acc_scr[...]
+        m_ref[...] = m_scr[...]
+        l_ref[...] = l_scr[...]
+
+
+def _live_block(blk, pos_b, *, bk, sq, window, last):
+    """Clamp a KV block index into the slot's live range.
+
+    Dead blocks are skipped by the kernel body; pointing their DMA at
+    an already-fetched live block lets the pipeline elide the copy, so
+    HBM traffic follows occupancy rather than the horizon.
+    """
+    hi = jnp.minimum((pos_b + (sq - 1)) // bk, last)
+    lo = 0 if window is None else jnp.maximum((pos_b - window + 1) // bk, 0)
+    return jnp.maximum(jnp.minimum(blk, hi), jnp.minimum(lo, hi))
+
+
+def _pack_q(q, hkv):
+    """(B, Sq, H, Dh) -> (B, Hkv, Sq*G, Dh): one row tile per KV head."""
+    b, sq, h, dh = q.shape
+    g = h // hkv
+    return q.reshape(b, sq, hkv, g, dh).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, sq * g, dh)
+
+
+def _unpack_o(o, sq):
+    """(B, Hkv, Sq*G, Dh) -> (B, Sq, H, Dh)."""
+    b, hkv, rows, dh = o.shape
+    g = rows // sq
+    return o.reshape(b, hkv, sq, g, dh).transpose(0, 2, 1, 3, 4).reshape(
+        b, sq, hkv * g, dh)
+
+
+def _partials_shapes(n_splits, b, hkv, rows, dh):
+    return [
+        jax.ShapeDtypeStruct((n_splits, b, hkv, rows, dh), jnp.float32),
+        jax.ShapeDtypeStruct((n_splits, b, hkv, rows, 1), jnp.float32),
+        jax.ShapeDtypeStruct((n_splits, b, hkv, rows, 1), jnp.float32),
+    ]
+
+
+def _partials_specs(hkv, rows, dh):
+    """Output BlockSpecs shared by the dense and paged grids."""
+    return [
+        pl.BlockSpec((None, None, hkv, rows, dh),
+                     lambda b_, s, ik, *_: (s, b_, 0, 0, 0)),
+        pl.BlockSpec((None, None, hkv, rows, 1),
+                     lambda b_, s, ik, *_: (s, b_, 0, 0, 0)),
+        pl.BlockSpec((None, None, hkv, rows, 1),
+                     lambda b_, s, ik, *_: (s, b_, 0, 0, 0)),
+    ]
+
+
+def _scratch(hkv, rows, dh):
+    return [pltpu.VMEM((hkv, rows, dh), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32)]
 
 
 def flash_decode(q, k, v, pos, *, window: int | None = None,
@@ -138,6 +190,7 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
     skv, hkv = k.shape[1], k.shape[2]
     g = h // hkv
     assert h == hkv * g and sq >= 1
+    rows = sq * g
     bk = max(1, min(bk, max(skv, 1)))
     nb = math.ceil(skv / bk)
     n_splits = max(1, min(n_splits, nb))
@@ -149,6 +202,11 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
         v = jnp.pad(v, padding)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     scale = 1.0 / math.sqrt(dh)
+    live = functools.partial(_live_block, bk=bk, sq=sq, window=window,
+                             last=n_splits * bps - 1)
+
+    def kv_map(b_, s, ik, p):
+        return (b_, live(s * bps + ik, p[b_]), 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, bk=bk, bps=bps, sq=sq, g=g, hkv=hkv, scale=scale,
@@ -157,35 +215,22 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
         num_scalar_prefetch=1,
         grid=(b, n_splits, bps),
         in_specs=[
-            pl.BlockSpec((1, sq, h, dh), lambda b_, s, ik, p: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, bk, hkv, dh),
-                         lambda b_, s, ik, p, n=bps:
-                         (b_, s * n + ik, 0, 0)),
-            pl.BlockSpec((1, bk, hkv, dh),
-                         lambda b_, s, ik, p, n=bps:
-                         (b_, s * n + ik, 0, 0)),
+            pl.BlockSpec((None, hkv, rows, dh),
+                         lambda b_, s, ik, p: (b_, 0, 0, 0)),
+            pl.BlockSpec((None, bk, hkv, dh), kv_map),
+            pl.BlockSpec((None, bk, hkv, dh), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, sq, h, dh),
-                         lambda b_, s, ik, p: (s, b_, 0, 0, 0)),
-            pl.BlockSpec((1, 1, sq, h), lambda b_, s, ik, p: (s, b_, 0, 0)),
-            pl.BlockSpec((1, 1, sq, h), lambda b_, s, ik, p: (s, b_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hkv, sq * g, dh), jnp.float32),
-            pltpu.VMEM((hkv, sq * g), jnp.float32),
-            pltpu.VMEM((hkv, sq * g), jnp.float32),
-        ])
+        out_specs=_partials_specs(hkv, rows, dh),
+        scratch_shapes=_scratch(hkv, rows, dh))
     o_part, m_part, l_part = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_splits, b, sq, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((n_splits, b, sq, h), jnp.float32),
-            jax.ShapeDtypeStruct((n_splits, b, sq, h), jnp.float32),
-        ],
-        interpret=interpret)(pos_arr, q, k, v)
-    return combine_splits(o_part, m_part, l_part).astype(q.dtype)
+        out_shape=_partials_shapes(n_splits, b, hkv, rows, dh),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SEMANTICS),
+        interpret=interpret)(pos_arr, _pack_q(q, hkv), k, v)
+    o = combine_splits(o_part, m_part[..., 0], l_part[..., 0])
+    return _unpack_o(o, sq).astype(q.dtype)
 
 
 def _paged_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
@@ -212,17 +257,18 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     page its logical block lives in — the gather *is* the block
     indexing, no materialized (B, NB*page, ...) cache ever exists.
 
-    The KV block equals the page size (one page per grid step) and the
-    block-level early-out is unchanged: it tests the *logical* block
-    start against ``pos``, so out-of-order physical tables cost
-    nothing. Entries beyond a slot's live pages may be arbitrary valid
-    page ids (they are fetched but fully masked). Returns
+    The KV block equals the page size (one page per grid step). The
+    block-level early-out is unchanged: it tests
+    the *logical* block start against ``pos``, so out-of-order physical
+    tables cost nothing. Entries beyond a slot's live pages may be
+    arbitrary valid page ids (they are never read). Returns
     (B, Sq, H, Dh) in q's dtype.
     """
     b, sq, h, dh = q.shape
     ps, hkv = k_pages.shape[1], k_pages.shape[2]
     g = h // hkv
     assert h == hkv * g and sq >= 1
+    rows = sq * g
     nb = block_tables.shape[1]
     n_splits = max(1, min(n_splits, nb))
     bps = math.ceil(nb / n_splits)
@@ -234,6 +280,11 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
         bt = jnp.pad(bt, [(0, 0), (0, n_splits * bps - nb)])
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     scale = 1.0 / math.sqrt(dh)
+    live = functools.partial(_live_block, bk=ps, sq=sq, window=window,
+                             last=nb - 1)
+
+    def kv_map(b_, s, ik, p, t):
+        return (t[b_, live(s * bps + ik, p[b_])], 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, bk=ps, bps=bps, sq=sq, g=g, hkv=hkv,
@@ -242,38 +293,22 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
         num_scalar_prefetch=2,
         grid=(b, n_splits, bps),
         in_specs=[
-            pl.BlockSpec((1, sq, h, dh),
+            pl.BlockSpec((None, hkv, rows, dh),
                          lambda b_, s, ik, p, t: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, dh),
-                         lambda b_, s, ik, p, t, n=bps:
-                         (t[b_, s * n + ik], 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, dh),
-                         lambda b_, s, ik, p, t, n=bps:
-                         (t[b_, s * n + ik], 0, 0, 0)),
+            pl.BlockSpec((None, ps, hkv, dh), kv_map),
+            pl.BlockSpec((None, ps, hkv, dh), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, sq, h, dh),
-                         lambda b_, s, ik, p, t: (s, b_, 0, 0, 0)),
-            pl.BlockSpec((1, 1, sq, h),
-                         lambda b_, s, ik, p, t: (s, b_, 0, 0)),
-            pl.BlockSpec((1, 1, sq, h),
-                         lambda b_, s, ik, p, t: (s, b_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((hkv, sq * g, dh), jnp.float32),
-            pltpu.VMEM((hkv, sq * g), jnp.float32),
-            pltpu.VMEM((hkv, sq * g), jnp.float32),
-        ])
+        out_specs=_partials_specs(hkv, rows, dh),
+        scratch_shapes=_scratch(hkv, rows, dh))
     o_part, m_part, l_part = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_splits, b, sq, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((n_splits, b, sq, h), jnp.float32),
-            jax.ShapeDtypeStruct((n_splits, b, sq, h), jnp.float32),
-        ],
-        interpret=interpret)(pos_arr, bt, q, k_pages, v_pages)
-    return combine_splits(o_part, m_part, l_part).astype(q.dtype)
+        out_shape=_partials_shapes(n_splits, b, hkv, rows, dh),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_SEMANTICS),
+        interpret=interpret)(pos_arr, bt, _pack_q(q, hkv), k_pages, v_pages)
+    o = combine_splits(o_part, m_part[..., 0], l_part[..., 0])
+    return _unpack_o(o, sq).astype(q.dtype)
 
 
 def ref_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
@@ -296,12 +331,12 @@ def ref_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
 def combine_splits(o_part, m_part, l_part) -> jax.Array:
     """Merge per-split partial softmax states (flash-decoding combine).
 
-    o_part: (S, B, Sq, H, Dh) unnormalized accumulators; m_part /
-    l_part: (S, B, Sq, H) running max / sum per split. Splits whose
-    blocks were all skipped carry (m=NEG_INF, l=0) and contribute
-    exactly zero weight. Returns (B, Sq, H, Dh) f32.
+    o_part: (S, ..., Dh) unnormalized accumulators; m_part / l_part:
+    (S, ...) running max / sum per split. Splits whose blocks were all
+    skipped carry (m=NEG_INF, l=0) and contribute exactly zero weight.
+    Returns (..., Dh) f32.
     """
-    m_max = m_part.max(axis=0)                           # (B,Sq,H)
+    m_max = m_part.max(axis=0)
     w = jnp.exp(m_part - m_max[None])                    # dead split -> 0
     l_tot = (l_part * w).sum(axis=0)
     o = (o_part * w[..., None]).sum(axis=0)

@@ -20,13 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SCRATCH = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 

@@ -23,8 +23,34 @@ from repro.kernels import tuning
 from repro.kernels.attention import decode as D
 from repro.kernels.attention import flash as F
 from repro.kernels.attention import ref as R
+from repro.utils.sharding import current_mesh_rules, mesh_axis_sizes, spec_for
 
 
+def _per_shard(kernel, q, k, v, pos, *tables, paged: bool = False):
+    """Run a Pallas decode kernel on each device's own heads.
+
+    GSPMD cannot partition a Mosaic kernel, so under an ambient mesh
+    (``repro.utils.sharding.use_mesh_rules``) the call goes through
+    ``shard_map``: heads split over the ``kvheads`` axes — whole GQA
+    groups per shard (``validate_tp_heads``) — and slots over the
+    ``batch`` axes; page pools keep every page on every shard. Without
+    a mesh it is the plain call.
+    """
+    b = q.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    mesh, rules = current_mesh_rules()
+    if mesh is None:
+        return kernel(q, k, v, pos, *tables)
+    from jax.sharding import PartitionSpec as P
+    sizes = mesh_axis_sizes(mesh)
+    bs = spec_for((b,), ("batch",), rules, sizes)[0]
+    hs = spec_for((k.shape[2],), ("kvheads",), rules, sizes)[0]
+    qs = P(bs, None, hs, None)
+    kvs = P(None, None, hs, None) if paged else qs
+    in_specs = (qs, kvs, kvs, P(bs)) + (P(bs, None),) * len(tables)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=qs, check_vma=False)(q, k, v, pos,
+                                                        *tables)
 
 
 def validate_tp_heads(h: int, hkv: int, dh: int, tp: int, *,
@@ -127,9 +153,9 @@ def flash_decode(q, k, v, pos, *, window=None, impl="auto", bk=None,
         k = k[:, :bound]
         v = v[:, :bound]
     if use_pallas(impl):
-        return D.flash_decode(q, k, v, pos, window=window, bk=bk,
-                              n_splits=n_splits,
-                              interpret=interpret_mode())
+        return _per_shard(
+            partial(D.flash_decode, window=window, bk=bk, n_splits=n_splits,
+                    interpret=interpret_mode()), q, k, v, pos)
     return D.ref_decode(q, k, v, pos, window=window)
 
 
@@ -165,8 +191,11 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
                                        skv=nb * ps, dh=dh, h=h, hkv=hkv,
                                        batch=b, dtype=str(q.dtype))
             n_splits = plan.n_splits
-        return D.flash_decode_paged(q, k_pages, v_pages, block_tables,
-                                    pos, window=window, n_splits=n_splits,
-                                    interpret=interpret_mode())
+        def kernel(q, k, v, pos, bt):
+            return D.flash_decode_paged(q, k, v, bt, pos, window=window,
+                                        n_splits=n_splits,
+                                        interpret=interpret_mode())
+        return _per_shard(kernel, q, k_pages, v_pages, pos, block_tables,
+                          paged=True)
     return D.ref_decode_paged(q, k_pages, v_pages, block_tables, pos,
                               window=window)

@@ -52,11 +52,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover - non-TPU pallas builds
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import wa
 from repro.kernels import interpret_mode, on_tpu
@@ -215,8 +211,6 @@ def _kv_write_nt(cache, update, pos, *, interpret: bool):
     ``B * Sq`` (Hkv, Dh) rows move — no whole-buffer copy and no
     read-modify-write of untouched rows.
     """
-    if pltpu is None:  # pragma: no cover - non-TPU pallas builds
-        raise RuntimeError("pallas TPU frontend unavailable")
     b, _, hkv, dh = cache.shape
     sq = update.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))

@@ -19,12 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:                      # element-indexed dims for stencil halos
-    import jax._src.pallas.core as _pc
-    Element = _pc.Element
-except Exception:         # pragma: no cover - API drift guard
-    Element = None
-
 DEFAULT_BM = 256          # rows per block
 DEFAULT_BN = 512          # cols per block (multiple of 128)
 
@@ -272,17 +266,10 @@ def jacobi_2d5pt(u, *, bm=64, interpret=False):
     m = h - 2
     bm = min(bm, m)
     assert m % bm == 0, (h, bm)
-    if Element is None:   # jax without element-indexed dims: no halo
-        # tiling available — run the same kernel as one whole-array block
-        return pl.pallas_call(
-            _jacobi2d_kernel, grid=(1,),
-            in_specs=[pl.BlockSpec((h, w), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((m, w - 2), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((m, w - 2), u.dtype),
-            interpret=interpret)(u)
     return pl.pallas_call(
         _jacobi2d_kernel, grid=(m // bm,),
-        in_specs=[pl.BlockSpec((Element(bm + 2), w), lambda i: (i * bm, 0))],
+        in_specs=[pl.BlockSpec((pl.Element(bm + 2), w),
+                               lambda i: (i * bm, 0))],
         out_specs=pl.BlockSpec((bm, w - 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, w - 2), u.dtype),
         interpret=interpret)(u)
@@ -302,17 +289,9 @@ def jacobi_3d7pt(u, *, bz=8, interpret=False):
     m = d - 2
     bz = min(bz, m)
     assert m % bz == 0, (d, bz)
-    if Element is None:   # see jacobi_2d5pt: whole-array fallback
-        return pl.pallas_call(
-            _jacobi3d_kernel, grid=(1,),
-            in_specs=[pl.BlockSpec((d, h, w), lambda i: (0, 0, 0))],
-            out_specs=pl.BlockSpec((m, h - 2, w - 2),
-                                   lambda i: (0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((m, h - 2, w - 2), u.dtype),
-            interpret=interpret)(u)
     return pl.pallas_call(
         _jacobi3d_kernel, grid=(m // bz,),
-        in_specs=[pl.BlockSpec((Element(bz + 2), h, w),
+        in_specs=[pl.BlockSpec((pl.Element(bz + 2), h, w),
                                lambda i: (i * bz, 0, 0))],
         out_specs=pl.BlockSpec((bz, h - 2, w - 2), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, h - 2, w - 2), u.dtype),

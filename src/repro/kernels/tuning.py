@@ -118,13 +118,18 @@ class TilePlan:
 def default_machine() -> str:
     """The machine tiles are tuned for when the caller names none.
 
-    On a real TPU backend the registered chip models are authoritative
-    (``tpu_v5e`` is the fleet's default target); elsewhere prefer the
-    ubench-calibrated ``host_cpu`` when it exists, falling back to the
-    TPU default — the kernels only ever *execute* on TPU anyway.
+    On a real TPU backend it is the chip the process runs on, read off
+    ``device_kind`` (``repro.utils.hw.chip_for_kind``; an unknown kind
+    raises). Elsewhere prefer the ubench-calibrated ``host_cpu`` when it
+    exists, falling back to ``tpu_v5e`` — the kernels only ever
+    *execute* on TPU anyway.
     """
     from repro.kernels import on_tpu
-    if not on_tpu() and "host_cpu" in MACHINES:
+    if on_tpu():
+        import jax
+        from repro.utils.hw import chip_for_kind
+        return chip_for_kind(jax.devices()[0].device_kind)
+    if "host_cpu" in MACHINES:
         return "host_cpu"
     return "tpu_v5e"
 

@@ -23,7 +23,6 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import SHAPES, ModelConfig, ShapeSpec, shapes_for
@@ -33,7 +32,8 @@ from repro.optim.adamw import OptConfig
 from repro.train import serve as serve_lib
 from repro.train import step as step_lib
 from repro.utils.sharding import (SERVE_FSDP_RULES, SERVE_RULES, TRAIN_RULES,
-                                  mesh_axis_sizes, use_mesh_rules)
+                                  mesh_axis_sizes, named_shardings,
+                                  use_mesh_rules)
 
 COLLECTIVE_RE = re.compile(
     r"""(?P<dtype>[a-z0-9]+)\[(?P<dims>[\d,]*)\][^=]*=\s*
@@ -67,11 +67,6 @@ def input_specs(arch: str, shape_name: str) -> dict:
     return step_lib.batch_shapes(cfg, shape)
 
 
-def _named(mesh, tree):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
-                        is_leaf=lambda x: isinstance(x, P))
-
-
 def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
                donate: bool = True, oc: "OptConfig | None" = None,
                decode_loop: int = 0, serve_variant: str = "resident2d"):
@@ -84,9 +79,10 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
         fn = step_lib.make_train_step(cfg, oc, accum)
         state_shapes = step_lib.train_state_shapes(cfg, oc)
         bshapes = step_lib.batch_shapes(cfg, shape)
-        state_sh = _named(mesh, step_lib.train_state_pspecs(cfg, rules,
-                                                            sizes, oc))
-        batch_sh = _named(mesh, step_lib.batch_pspecs(cfg, bshapes, rules, sizes))
+        state_sh = named_shardings(mesh, step_lib.train_state_pspecs(
+            cfg, rules, sizes, oc))
+        batch_sh = named_shardings(
+            mesh, step_lib.batch_pspecs(cfg, bshapes, rules, sizes))
         jfn = jax.jit(fn, in_shardings=(state_sh, batch_sh),
                       out_shardings=(state_sh, None),
                       donate_argnums=(0,) if donate else ())
@@ -104,14 +100,15 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     else:
         rules = SERVE_FSDP_RULES
     pshapes = M.param_shapes(cfg)
-    p_sh = _named(mesh, M.param_pspecs(cfg, rules, sizes))
+    p_sh = named_shardings(mesh, M.param_pspecs(cfg, rules, sizes))
     bshapes = step_lib.batch_shapes(cfg, shape)
-    batch_sh = _named(mesh, step_lib.batch_pspecs(cfg, bshapes, rules, sizes))
+    batch_sh = named_shardings(
+        mesh, step_lib.batch_pspecs(cfg, bshapes, rules, sizes))
     meta = {"serve_fsdp": fsdp, "rules": "serve_fsdp" if fsdp else "serve"}
 
     if shape.kind == "prefill":
         fn = serve_lib.make_prefill_step(cfg)
-        cache_sh = _named(mesh, M.cache_pspecs(cfg, rules, sizes,
+        cache_sh = named_shardings(mesh, M.cache_pspecs(cfg, rules, sizes,
                                                shape.global_batch,
                                                shape.seq_len))
         jfn = jax.jit(fn, in_shardings=(p_sh, batch_sh),
@@ -125,7 +122,7 @@ def lower_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     else:
         fn = serve_lib.make_decode_step(cfg)
     cshapes = M.cache_shapes(cfg, shape.global_batch, shape.seq_len)
-    cache_sh = _named(mesh, M.cache_pspecs(cfg, rules, sizes,
+    cache_sh = named_shardings(mesh, M.cache_pspecs(cfg, rules, sizes,
                                            shape.global_batch, shape.seq_len))
     jfn = jax.jit(fn, in_shardings=(p_sh, cache_sh, batch_sh, None),
                   out_shardings=(None, cache_sh),

@@ -6,6 +6,11 @@ touches jax device state. The single-pod mesh is 16x16 = 256 chips
 ("pod", "data", "model") — the "pod" axis is a pure extra data-parallel
 axis whose gradient all-reduce crosses the inter-pod (DCN) boundary once
 per step.
+
+Every mesh here has ``Auto`` axes: the model code places arrays with
+``with_sharding_constraint`` and lets GSPMD propagate, which is what
+``jax.make_mesh``'s default ``Explicit`` axes refuse (an embedding
+gather over a sharded table raises ``ShardingTypeError`` there).
 """
 
 from __future__ import annotations
@@ -13,6 +18,14 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes over ``devices`` (default: all)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,13 +38,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, have {len(devs)}; "
             "run under XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n} (see repro.launch.dryrun)")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_test_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many real devices exist (tests/smoke)."""
     n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=jax.devices()[:n])
 
 
 def make_serve_mesh(spec: str | None):
@@ -62,4 +75,4 @@ def make_serve_mesh(spec: str | None):
         raise RuntimeError(
             f"mesh {spec!r} needs {n} devices, have {len(devs)}; run "
             f"under XLA_FLAGS=--xla_force_host_platform_device_count={n}")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devices=devs[:n])

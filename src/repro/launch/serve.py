@@ -8,13 +8,19 @@ instead of one per token.
 
   PYTHONPATH=src python -m repro.launch.serve --arch xlstm-125m --smoke \
       --batch 4 --prompt-len 64 --gen 32
+
+Compiled programs persist across runs: where ``JAX_COMPILATION_CACHE_DIR``
+is set jax keeps its cache there, and otherwise :func:`use_compile_cache`
+points it at ``.jax_cache`` in the checkout.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import time
+from functools import partial
 
 import jax
 
@@ -27,6 +33,28 @@ from repro.serve import Request, ServeEngine
 #: consumed here so the driver and the env script agree on one table.
 _DTYPE_POLICIES = {"bf16": "bfloat16", "tf32": "tensorfloat32",
                    "f32": "highest"}
+
+
+#: the checkout root (src/repro/launch/serve.py -> three levels up)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to jax, which reads
+    it itself. Otherwise the cache goes to ``<checkout>/.jax_cache`` —
+    one fixed path, because the path is part of what a later run must
+    find again. Call it before the first compile of the process: jax
+    settles its cache at that compile.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def apply_runtime_policy(env: dict | None = None) -> dict:
@@ -98,12 +126,17 @@ def generate(cfg, params, prompt_tokens, gen_len: int, *,
                                 mesh=mesh).chunk
     replicas = max(1, int(replicas))
     slots = -(-b // replicas)
+    # unsharded replicas go one per device (round-robin when there are
+    # more replicas than devices); sharded ones all span the mesh
+    devs = jax.devices()
+    devices = [None if replicas == 1 or mesh is not None
+               else devs[i % len(devs)] for i in range(replicas)]
     engines = [ServeEngine(cfg, params, max_slots=slots,
                            max_len=s + gen_len,
                            chunk=min(chunk or 1, max(1, gen_len - 1)),
                            temperature=temperature, seed=seed, mesh=mesh,
-                           pipeline=pipeline)
-               for _ in range(replicas)]
+                           pipeline=pipeline, device=dev)
+               for dev in devices]
     prompts = np.asarray(prompt_tokens)
     reqs = [Request(rid=str(i), prompt=tuple(int(t) for t in prompts[i]),
                     max_new_tokens=gen_len) for i in range(b)]
@@ -120,7 +153,35 @@ def generate(cfg, params, prompt_tokens, gen_len: int, *,
     return jnp.stack([jnp.asarray(results[str(i)]) for i in range(b)])
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class ServeRun:
+    """What :func:`main` served: tokens and the objects that made them."""
+
+    cfg: object
+    params: dict
+    prompts: jax.Array       # (batch, prompt_len) int32
+    tokens: jax.Array        # (batch, gen) int32
+    engines: list
+
+
+def init_params(cfg, key, mesh=None):
+    """Seeded parameters, created directly in the mesh layout if given.
+
+    A sharded model may not fit one device, so with a mesh the init is
+    one jitted program whose outputs are already laid out for the serve
+    engine (``SERVE_ENGINE_RULES``).
+    """
+    if mesh is None:
+        return M.init_params(cfg, key)
+    from repro.utils.sharding import (SERVE_ENGINE_RULES, mesh_axis_sizes,
+                                      named_shardings)
+    shardings = named_shardings(mesh, M.param_pspecs(
+        cfg, SERVE_ENGINE_RULES, mesh_axis_sizes(mesh)))
+    return jax.jit(partial(M.init_params, cfg),
+                   out_shardings=shardings)(key)
+
+
+def main(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--smoke", action="store_true")
@@ -153,6 +214,7 @@ def main(argv=None):
                          "online planning, bit-identically)")
     args = ap.parse_args(argv)
 
+    print(f"compile cache: {use_compile_cache()}")
     policy = apply_runtime_policy()
     for k, v in sorted(policy.items()):
         print(f"runtime policy: {k}: {v}")
@@ -169,7 +231,7 @@ def main(argv=None):
     # params and prompts must be independent streams: reusing one key for
     # both correlates the prompt ids with the embedding init
     k_params, k_prompts = jax.random.split(key)
-    params = M.init_params(cfg, k_params)
+    params = init_params(cfg, k_params, mesh)
     prompts = jax.random.randint(k_prompts, (args.batch, args.prompt_len),
                                  0, cfg.vocab_size)
     eng_out: list = []
@@ -192,7 +254,8 @@ def main(argv=None):
           f"(chunk={eng.chunk}) + {eng.prefill_dispatches} prefill"
           f"{shard}{repl}{pipe} | mean dispatch gap {1e3 * gap:.2f}ms")
     print("sample:", toks[0, :16].tolist())
-    return toks
+    return ServeRun(cfg=cfg, params=params, prompts=prompts, tokens=toks,
+                    engines=eng_out)
 
 
 if __name__ == "__main__":

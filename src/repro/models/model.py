@@ -426,7 +426,7 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, *, local: bool, mode: str,
         kc = sc(kc, None, None, "kvheads", None)
         vc = sc(vc, None, None, "kvheads", None)
         y = attn_lib.decode_attention(q, kc, vc, pos, window=window,
-                                      impl=attn_impl or "ref",
+                                      impl=attn_impl or "auto",
                                       kv_len=kv_len,
                                       block_tables=block_tables)
         new_cache = {"k": kc, "v": vc}
@@ -441,7 +441,7 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, *, local: bool, mode: str,
         kc = sc(kc, "batch", "kv_seq", "kvheads", None)
         vc = sc(vc, "batch", "kv_seq", "kvheads", None)
         y = attn_lib.decode_attention(q, kc, vc, pos, window=window,
-                                      impl=attn_impl or "ref",
+                                      impl=attn_impl or "auto",
                                       kv_len=kv_len)
         new_cache = {"k": kc, "v": vc}
     else:
@@ -562,7 +562,8 @@ def forward(cfg: ModelConfig, params: dict, batch: dict, *,
                       batching: each row attends/updates at its own pos).
                       `attn_impl` routes decode attention through the
                       split-KV kernel suite ("ref"/"pallas"/"auto", see
-                      models.attention.decode_attention) and `kv_len`
+                      models.attention.decode_attention; None = "auto":
+                      the Pallas kernel on TPU) and `kv_len`
                       statically bounds how much of the cache horizon a
                       step may read (occupancy bound, repro.serve).
     `store_flavor` ("standard"|"nt"|"auto", None = standard) picks the

@@ -50,14 +50,8 @@ from repro.serve.slots import make_insert_step
 from repro.serve.staging import PromptStager
 from repro.train import serve as serve_lib
 from repro.utils.sharding import (SERVE_ENGINE_RULES, mesh_axis_sizes,
-                                  named_sharding, tp_degree, use_mesh_rules)
-
-
-def _named(mesh, pspecs):
-    """PartitionSpec tree -> NamedSharding tree (P is a tuple: mark leaves)."""
-    from jax.sharding import PartitionSpec as P
-    return jax.tree.map(lambda s: named_sharding(mesh, s), pspecs,
-                        is_leaf=lambda x: isinstance(x, P))
+                                  named_shardings, tp_degree,
+                                  use_mesh_rules)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,8 +125,11 @@ class ServeEngine:
                  mesh=None, rules: dict | None = None,
                  nonfinite_guard: bool = True,
                  pipeline: bool | int = 0,
-                 stage_depth: int = 8):
+                 stage_depth: int = 8,
+                 device=None):
         assert cfg.embed_inputs, "serve engine needs a token-id model"
+        if device is not None and mesh is not None:
+            raise ValueError("pass a mesh or a device, not both")
         self.cfg, self.params = cfg, params
         self.max_slots, self.max_len = max_slots, max_len
         self.temperature = float(temperature)
@@ -152,7 +149,7 @@ class ServeEngine:
         self._t_enqueued: float | None = None
         # async H2D prompt staging (repro.serve.staging): stage() ahead
         # of admission, admit() takes the already-resident array
-        self.stager = PromptStager(depth=stage_depth)
+        self.stager = PromptStager(depth=stage_depth, device=device)
         # the non-finite guard makes every decode chunk also return a
         # per-slot isfinite flag (serve.decode guard=): a slot whose
         # logits went NaN/inf is quarantined — removed from its slot
@@ -179,7 +176,9 @@ class ServeEngine:
         # kv_seq resident), the step functions trace with the ambient
         # mesh+rules installed (sc() constraints go live), and the
         # planner prices the per-shard KV stream + per-step collective.
-        self.mesh = mesh
+        # device pins an unsharded engine (params, cache, staged prompts)
+        # to one device — one replica per chip behind the router
+        self.mesh, self.device = mesh, device
         self.rules = (rules if rules is not None else SERVE_ENGINE_RULES) \
             if mesh is not None else None
         self._mesh_sizes = mesh_axis_sizes(mesh) if mesh is not None else {}
@@ -189,8 +188,10 @@ class ServeEngine:
                               cfg.head_dim_eff, self.tp,
                               page_size=getattr(self, "page_size", None))
             self.params = jax.device_put(
-                params, _named(mesh, M.param_pspecs(cfg, self.rules,
+                params, named_shardings(mesh, M.param_pspecs(cfg, self.rules,
                                                     self._mesh_sizes)))
+        elif device is not None:
+            self.params = jax.device_put(params, device)
         if chunk is None:
             self.plan = self._make_plan(machine)
             chunk = self.plan.chunk
@@ -234,10 +235,12 @@ class ServeEngine:
         return wrapped
 
     def _shard_cache(self, cache, pspecs):
-        """Commit a fresh cache to its mesh layout (no-op unsharded)."""
-        if self.mesh is None:
-            return cache
-        return jax.device_put(cache, _named(self.mesh, pspecs))
+        """Commit a fresh cache to its mesh layout or its device."""
+        if self.mesh is not None:
+            return jax.device_put(cache, named_shardings(self.mesh, pspecs))
+        if self.device is not None:
+            return jax.device_put(cache, self.device)
+        return cache
 
     def _donate(self) -> tuple:
         """Cache-donation argnums for the decode jit, mode-dependent.

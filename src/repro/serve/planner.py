@@ -284,8 +284,11 @@ def plan_chunk_size(cfg: ModelConfig, batch: int, max_len: int, *,
                                       rules_fingerprint, tp_degree)
     backend = get_backend(backend).name     # canonical (aliases fold)
     if machine is None:
+        from repro.kernels import on_tpu
+        from repro.kernels.tuning import default_machine
         names = registered_names()
-        machine = "host_cpu" if "host_cpu" in names else names[0]
+        machine = default_machine() if on_tpu() else (
+            "host_cpu" if "host_cpu" in names else names[0])
     if mesh is not None and rules is None:
         rules = SERVE_ENGINE_RULES
     if mesh is not None:

@@ -141,6 +141,29 @@ TPU_V4 = ChipSpec(
 
 CHIPS = {c.name: c for c in (TPU_V5E, TPU_V5P, TPU_V4)}
 
+#: ``jax.Device.device_kind`` -> the ChipSpec (and registered machine)
+#: that models it. The kind strings are the ones libtpu reports for each
+#: generation (``jax.experimental.topologies.get_topology_desc`` on
+#: "v5e:2x2", "v5p:2x2x1" and "v4:2x2x1"); the peaks above are Google
+#: Cloud's published per-chip figures ("TPU v5e", "TPU v5p", "TPU v4").
+TPU_KINDS = {"TPU v5 lite": "tpu_v5e", "TPU v5": "tpu_v5p",
+             "TPU v4": "tpu_v4"}
+
+
+def chip_for_kind(device_kind: str) -> str:
+    """Machine name for a TPU ``device_kind``; unknown kinds raise.
+
+    A chip that is not in :data:`TPU_KINDS` has no peaks and no port
+    model here, and pricing it as some other generation would mislabel
+    every number derived from it.
+    """
+    try:
+        return TPU_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no machine model for TPU device_kind {device_kind!r}; "
+            f"known kinds: {sorted(TPU_KINDS)}") from None
+
 
 # --- the paper's actual CPUs (Table I / Table II core features) -------------
 

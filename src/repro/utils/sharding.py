@@ -85,6 +85,11 @@ def use_mesh_rules(mesh: Mesh | None, rules: dict | None):
         _STATE.mesh, _STATE.rules = prev
 
 
+def current_mesh_rules() -> tuple:
+    """The ambient ``(mesh, rules)`` of :func:`use_mesh_rules`, or Nones."""
+    return _STATE.mesh, _STATE.rules
+
+
 def mesh_axis_sizes(mesh: Mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
@@ -155,5 +160,7 @@ def sc(x: jax.Array, *axes) -> jax.Array:
         x, NamedSharding(_STATE.mesh, spec))
 
 
-def named_sharding(mesh: Mesh, spec: P) -> NamedSharding:
-    return NamedSharding(mesh, spec)
+def named_shardings(mesh: Mesh, pspecs):
+    """PartitionSpec tree -> NamedSharding tree (P is a tuple: mark leaves)."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
+                        is_leaf=lambda x: isinstance(x, P))

@@ -70,6 +70,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, json
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_test_mesh
 from repro.optim.adamw import OptConfig
 from repro.train import step as step_lib
 from repro.utils.sharding import TRAIN_RULES, mesh_axis_sizes, use_mesh_rules
@@ -77,7 +78,7 @@ from repro.configs.base import ShapeSpec
 import repro.models.model as M
 
 cfg = get_smoke_config("yi-9b")
-mesh = jax.make_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+mesh = make_test_mesh((2, 2))
 sizes = mesh_axis_sizes(mesh)
 shape = ShapeSpec("mini", 64, 4, "train")
 fn = step_lib.make_train_step(cfg, OptConfig(), 1)
@@ -100,3 +101,22 @@ print(json.dumps({"ok": True, "temp": int(ma.temp_size_in_bytes)}))
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["ok"] and rec["temp"] > 0
+
+
+def test_compile_cache_goes_to_env_or_one_fixed_path(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to jax; without it the
+    cache is <checkout>/.jax_cache, the same path on every call."""
+    import jax
+    from repro.launch import serve
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert serve.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = serve.use_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert serve.use_compile_cache() == path
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -248,3 +248,13 @@ def test_machine_store_traffic_ordering_on_real_module():
     w = wa.machine_store_traffic(txt, "zen4")
     assert w["traffic_bytes"] >= w["stored_bytes"] > 0
     assert w["wa_mode"] == "explicit_only"
+
+
+def test_tpu_machine_comes_from_device_kind():
+    """One table maps a TPU's device_kind to its machine; an unknown
+    kind raises instead of pricing the chip as some other one."""
+    from repro.utils.hw import CHIPS, TPU_KINDS, chip_for_kind
+    assert chip_for_kind("TPU v5 lite") == "tpu_v5e"
+    assert set(TPU_KINDS.values()) <= set(CHIPS) <= set(MACHINES)
+    with pytest.raises(ValueError, match="device_kind"):
+        chip_for_kind("TPU v6 lite")

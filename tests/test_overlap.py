@@ -12,6 +12,7 @@ import pytest
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
 from repro.serve import (PagedServeEngine, PromptStager, ReplicaRouter,
                          Request, ServeEngine)
@@ -186,7 +187,7 @@ def test_engine_staging_used_on_admit(cfg, params):
 def test_sharded_engine_declines_staging(cfg, params):
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh((1, 1))
     eng = ServeEngine(cfg, params, max_slots=SLOTS, max_len=MAX_LEN,
                       chunk=CHUNK, mesh=mesh)
     assert eng.stage(_requests(cfg, 1)[0]) is False

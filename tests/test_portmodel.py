@@ -72,8 +72,6 @@ def test_serial_floor_on_sequential_scan():
 
 
 def test_collective_accounting():
-    import numpy as np
-    mesh = jax.make_mesh((1,), ("x",), devices=jax.devices()[:1])
     # single-device: no collectives expected; exercise the parser path
     txt = _compile_text(lambda a: a.sum(), ((128, 128), jnp.float32))
     rep = portmodel.analyze(txt, TPU_V5E)
@@ -196,3 +194,19 @@ def test_full_machines_never_fall_back():
     txt = _chain_text()
     for name, rep in portmodel.compare(txt, parallel="serial").items():
         assert rep.fallback_uops == 0, name
+
+
+def test_compare_never_forks_with_accelerator_live(monkeypatch):
+    """A process that holds an accelerator runs the fan-out serially,
+    even when the pool is forced."""
+    txt = _chain_text()
+    serial = portmodel.compare(txt, parallel="serial")
+
+    def no_pool(*a, **kw):
+        raise AssertionError("forked a pool with an accelerator live")
+
+    monkeypatch.setattr(portmodel, "_accelerator_live", lambda: True)
+    monkeypatch.setattr(portmodel, "ProcessPoolExecutor", no_pool)
+    pooled = portmodel.compare(txt, parallel="process")
+    assert {n: r.tp_cycles for n, r in pooled.items()} == \
+        {n: r.tp_cycles for n, r in serial.items()}

@@ -231,3 +231,29 @@ def test_zero_and_one_token_budgets():
     with pytest.raises(ValueError, match="prompt ids"):
         eng2.admit(Request("n", (-1, 2, 3), 2))
     assert eng2.free_slots() == [0]            # nothing half-admitted
+
+
+def test_engine_device_pin_places_params_and_cache():
+    """device= pins an unsharded engine to one device and changes no
+    token; generate() hands replica i device i (round-robin)."""
+    from repro.launch.serve import generate
+    cfg = get_smoke_config("yi-9b")
+    params = _params(cfg)
+    prompts = _prompts(cfg, 2, 6)
+    dev = jax.devices()[0]
+    base, _ = _run_engine(cfg, params, prompts, 4)
+    pinned, _ = _run_engine(cfg, params, prompts, 4, device=dev)
+    np.testing.assert_array_equal(base, pinned)
+    eng = ServeEngine(cfg, params, max_slots=2, max_len=16, chunk=2,
+                      device=dev)
+    held = {d for tree in (eng.params, eng.cache)
+            for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+    assert held == {dev}
+    out: list = []
+    generate(cfg, params, prompts, 3, chunk=2, replicas=2, engine_out=out)
+    devs = jax.devices()
+    assert [e.device for e in out] == [devs[i % len(devs)]
+                                       for i in range(2)]
+    with pytest.raises(ValueError, match="mesh or a device"):
+        ServeEngine(cfg, params, max_slots=2, max_len=16, chunk=2,
+                    device=dev, mesh=object())

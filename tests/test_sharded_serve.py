@@ -19,6 +19,7 @@ from hypothesis import given, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.mesh import make_test_mesh
 from repro.models import model as M
 from repro.serve import (PagedServeEngine, Request, ServeEngine,
                          collective_traffic, kv_read_seconds,
@@ -145,7 +146,7 @@ def test_engine_one_device_mesh_token_identity(cfg):
                     prompt=tuple(int(t) for t in
                                  rng.integers(0, cfg.vocab_size, 5)),
                     max_new_tokens=3) for i in range(3)]
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh((1, 1))
     base = ServeEngine(cfg, params, max_slots=2, max_len=16,
                        chunk=2).run(list(reqs))
     for cls, kw in ((ServeEngine, {}),
@@ -165,20 +166,33 @@ def test_engine_rejects_indivisible_heads(cfg):
                     mesh=_fake_mesh(model=3))
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_two_device_sharded_token_identity(layout):
-    """Acceptance pin: dense and paged engines sharded over a (1, 2)
-    host mesh serve token-identical streams to the unsharded engine."""
+def _two_device_child(*argv) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests",
-                                      "_sharded_serve_child.py"), layout],
+                                      "_sharded_serve_child.py"), *argv],
         env=env, capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_two_device_sharded_token_identity(layout):
+    """Acceptance pin: dense and paged engines sharded over a (1, 2)
+    host mesh serve token-identical streams to the unsharded engine."""
+    rec = _two_device_child(layout)
+    assert rec["tp"] == 2
+    assert rec["match"], f"sharded tokens diverged: {rec['tokens']}"
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_two_device_sharded_kernel_token_identity(layout):
+    """The Pallas decode kernels under a (1, 2) mesh (shard_map over the
+    KV heads) serve the unsharded kernel engine's streams."""
+    rec = _two_device_child(layout, "pallas")
     assert rec["tp"] == 2
     assert rec["match"], f"sharded tokens diverged: {rec['tokens']}"
 
