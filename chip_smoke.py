@@ -9,7 +9,9 @@ One process, random weights from a seed, the entry points a user calls
 checks that every request got its tokens as valid ids, that the decode
 program holds the Pallas kernel (``tpu_custom_call``), and that the
 kernel route agrees with the plain-JAX route on the first decode step's
-logits. Any failed check exits non-zero. The lines before the last are
+logits; the sharded phase also holds TP=4 to one device, in float32,
+and shows that a planted sharding fault fails that check. Any failed
+check exits non-zero. The lines before the last are
 smoke timings of this one run, compiles included; they are not
 metrics. The last line is one JSON object naming the device.
 
@@ -30,13 +32,24 @@ import time
 import numpy as np
 
 #: first-decode-step logits of two routes agree when
-#: max|a - b| <= LOGIT_RTOL * max|b|. The routes differ in where
-#: attention rounds to bf16 (8 significant bits) and in reduction
-#: order, layer after layer: at logits of magnitude ~1 that is a few
-#: bf16 steps (2**-7) of the largest logit, 0.014-0.023 of it at smoke
-#: widths over 6-34 layers on the CPU. A wrong attention result moves
-#: logits by O(1) of that scale.
-LOGIT_RTOL = 0.08
+#: max|a - b| <= LOGIT_RTOL * max|b|. In bf16 the kernel and the
+#: plain-JAX route differ in where attention rounds (8 significant
+#: bits) and in reduction order, layer after layer: gemma3-4b on a
+#: TPU v5e reads 0.01797 (dense) and 0.01899 (paged), the same in
+#: every run. A wrong attention result moves logits by O(1) of max|b|.
+LOGIT_RTOL = 0.04
+#: the same bound for TP=4 against one device, both in float32 with
+#: f32 matmuls ("highest"): the shards change only f32 summation
+#: order, so a deviation near the bf16 readings above means the split
+#: itself is wrong. A planted fault (KV heads moved one shard over)
+#: must read above it.
+F32_LOGIT_RTOL = 0.005
+#: one chip: this model at its published (layers, d_model, vocab)
+ARCH, WIDTHS = "gemma3-4b", (34, 2560, 262144)
+#: four chips: a model one v5e cannot hold, at TP over its 4 KV heads,
+#: compared with a cut of it that one chip holds in float32
+TP_ARCH, TP_MESH, CUT_LAYERS = "yi-9b", "data,model=1,4", 8
+REPLICAS = 4
 #: page size of the paged phase: 128 rows per (page, Hkv, Dh) DMA
 PAGE_SIZE = 128
 SEED = 0
@@ -118,7 +131,7 @@ def step_logits(eng, impl: str, cache, tok, pos, block_tables=None):
 
 
 def logit_delta(got, ref) -> dict:
-    """Relative max deviation and greedy agreement of two logit rows."""
+    """Relative max deviation and greedy agreement of logits (..., V)."""
     rel = float(np.abs(got - ref).max() / max(np.abs(ref).max(), 1e-30))
     agree = float((got.argmax(-1) == ref.argmax(-1)).mean())
     return {"rel_max_dlogit": rel, "argmax_agree": agree}
@@ -133,6 +146,74 @@ def dense_first_step(eng, prompts, impls=("auto", "ref")):
     return [step_logits(eng, impl, cache, tok, pos) for impl in impls]
 
 
+def forced_logits(eng, prompts, stream, *param_sets):
+    """Logits (B, T, V) along a given token stream (B, T), one array
+    per set of params run through ``eng``'s model.
+
+    Step 0 comes from the prefill and step t from decoding
+    ``stream[:, t - 1]``, whatever the route itself would have picked,
+    so two routes fed one stream see the same prefix at every step.
+    """
+    import jax
+    import jax.numpy as jnp
+    from repro.models import model as M
+
+    def fn(params, cache, tok, pos):
+        logits, _, cache = M.forward(eng.cfg, params, {"tokens": tok},
+                                     mode="decode", cache=cache, pos=pos,
+                                     attn_impl="auto")
+        return logits[:, 0].astype(jnp.float32), cache
+    step = jax.jit(eng._traced(fn), donate_argnums=(1,))
+    b, s = prompts.shape
+    outs = []
+    for params in param_sets:
+        logits, cache = eng._prefill(params, {"tokens": prompts})
+        out = [np.asarray(logits[:, -1].astype(jnp.float32))]
+        del logits
+        for t in range(1, stream.shape[1]):
+            lg, cache = step(params, cache,
+                             jnp.asarray(stream[:, t - 1:t], jnp.int32),
+                             jnp.full((b,), s + t - 1, jnp.int32))
+            out.append(np.asarray(lg))
+        outs.append(np.stack(out, axis=1))
+    return outs
+
+
+def first_divergence(got, ref, ref_logits, got_logits) -> list:
+    """Per stream, where two greedy streams (B, T) first part.
+
+    None where they never do; else (step, top-2 gap, deviation): the
+    reference's logit of its own token less that of the other stream's
+    token, and max|got - ref| of the step, both over max|ref| of the
+    step. A gap below the deviation marks a near-tie flip.
+    """
+    out = []
+    for i in range(ref.shape[0]):
+        steps = np.flatnonzero(got[i] != ref[i])
+        if steps.size == 0:
+            out.append(None)
+            continue
+        t = int(steps[0])
+        row, scale = ref_logits[i, t], np.abs(ref_logits[i, t]).max()
+        gap = (row[ref[i, t]] - row[got[i, t]]) / scale
+        dev = np.abs(got_logits[i, t] - row).max() / scale
+        out.append((t, float(gap), float(dev)))
+    return out
+
+
+def move_kv_heads(params):
+    """The planted fault: every layer's KV heads rolled one shard over."""
+    import jax
+    import jax.numpy as jnp
+
+    def roll(path, leaf):
+        name = getattr(path[-1], "key", None)
+        return jnp.roll(leaf, 1, axis=-2) if name in ("wk", "wv", "bk",
+                                                      "bv") else leaf
+    moved = jax.tree_util.tree_map_with_path(roll, params)
+    return jax.device_put(moved, jax.tree.map(lambda a: a.sharding, params))
+
+
 def requests(prompts, gen: int, prefix: str = "r"):
     from repro.serve import Request
     p = np.asarray(prompts)
@@ -140,30 +221,29 @@ def requests(prompts, gen: int, prefix: str = "r"):
                     max_new_tokens=gen) for i in range(p.shape[0])]
 
 
-def serve_args(arch: str, *extra, smoke: bool = False):
-    args = ["--arch", arch, "--batch", "4", "--prompt-len", "512",
+def serve_args(arch: str, *extra):
+    return ["--arch", arch, "--batch", "4", "--prompt-len", "512",
             "--gen", "64", "--seed", str(SEED), *extra]
-    return args + ["--smoke"] if smoke else args
 
 
 # --- one chip --------------------------------------------------------------
 
-def dense_phase(clock, *, smoke: bool = False):
+def dense_phase(clock):
     """gemma3-4b through ``repro.launch.serve.main``, chunk planned."""
     from repro.launch import serve
     t0, c0 = time.perf_counter(), clock.seconds
-    run = serve.main(serve_args("gemma3-4b", smoke=smoke))
+    run = serve.main(serve_args(ARCH))
     eng = run.engines[0]
     widths = (run.cfg.n_layers, run.cfg.d_model, run.cfg.vocab_size)
-    check(smoke or widths == (34, 2560, 262144),
-          f"gemma3-4b at (layers, d_model, vocab) = {widths}")
+    check(widths == WIDTHS,
+          f"{ARCH} at (layers, d_model, vocab) = {widths}")
     check_tokens({r.rid: run.tokens[i]
                   for i, r in enumerate(requests(run.prompts, 64))},
                  requests(run.prompts, 64), run.cfg.vocab_size)
     text = decode_program(eng)
     got, ref = dense_first_step(eng, run.prompts)
     delta = logit_delta(got, ref)
-    report("dense gemma3-4b 4x512+64", clock, t0, c0, chunk=eng.chunk,
+    report(f"dense {ARCH} 4x512+64", clock, t0, c0, chunk=eng.chunk,
            **delta)
     return run, text, delta
 
@@ -191,7 +271,7 @@ def paged_phase(clock, params, cfg):
     text = decode_program(eng)
     results = eng.run(reqs[4:])
     check_tokens(results, reqs, cfg.vocab_size)
-    report("paged gemma3-4b 8 reqs 4 slots", clock, t0, c0,
+    report(f"paged {ARCH} 8 reqs 4 slots", clock, t0, c0,
            chunk=eng.chunk, page_size=PAGE_SIZE, **delta)
     return text, delta
 
@@ -211,19 +291,16 @@ def one_chip(clock) -> None:
 
 # --- four chips ------------------------------------------------------------
 
-def sharded_phase(clock, mesh_spec: str = "data,model=1,4",
-                  cut_layers: int = 8, arch: str = "yi-9b",
-                  smoke: bool = False):
-    """yi-9b at TP=4 through main, then an 8-layer cut vs unsharded."""
+def sharded_phase(clock):
+    """yi-9b at TP=4 through main, then a float32 cut vs one device."""
     import jax
-    from repro.configs import get_config, get_smoke_config
+    from repro.configs import get_config
     from repro.launch import serve
     from repro.launch.mesh import make_serve_mesh
     from repro.models import model as M
     from repro.serve import ServeEngine
     t0, c0 = time.perf_counter(), clock.seconds
-    run = serve.main(serve_args(arch, "--mesh", mesh_spec, "--chunk", "8",
-                                smoke=smoke))
+    run = serve.main(serve_args(TP_ARCH, "--mesh", TP_MESH, "--chunk", "8"))
     eng = run.engines[0]
     check_tokens({r.rid: run.tokens[i]
                   for i, r in enumerate(requests(run.prompts, 64))},
@@ -234,40 +311,48 @@ def sharded_phase(clock, mesh_spec: str = "data,model=1,4",
           f"params span {spread} devices")
     check("tpu_custom_call" in decode_program(eng),
           "sharded decode program has no kernel")
-    report(f"sharded {arch} tp={eng.tp}", clock, t0, c0)
+    report(f"sharded {TP_ARCH} tp={eng.tp}", clock, t0, c0)
     prompts = run.prompts
     del run, eng
 
     t0, c0 = time.perf_counter(), clock.seconds
-    base = get_smoke_config(arch) if smoke else get_config(arch)
-    cfg = dataclasses.replace(base, n_layers=cut_layers)
-    params = M.init_params(cfg, jax.random.PRNGKey(SEED))
+    cfg = dataclasses.replace(get_config(TP_ARCH), n_layers=CUT_LAYERS,
+                              param_dtype="float32")
     reqs = requests(prompts, 64)
     kw = dict(max_slots=4, max_len=512 + 64, chunk=8)
-    solo = ServeEngine(cfg, params, **kw)
-    tp = ServeEngine(cfg, params, mesh=make_serve_mesh(mesh_spec), **kw)
-    (got,), (ref,) = (dense_first_step(e, prompts, ("auto",))
-                      for e in (tp, solo))
-    delta = logit_delta(got, ref)
-    out_s, out_1 = tp.run(list(reqs)), solo.run(list(reqs))
-    check_tokens(out_s, reqs, cfg.vocab_size)
-    agree = float(np.mean([np.mean(out_s[r.rid] == out_1[r.rid])
-                           for r in reqs]))
-    report(f"{arch} {cut_layers}-layer cut tp={tp.tp} vs unsharded", clock,
-           t0, c0, greedy_token_agree=agree, **delta)
-    check(delta["rel_max_dlogit"] <= LOGIT_RTOL,
-          f"TP={tp.tp} vs unsharded logits: {delta}")
-    return delta, agree
+    with jax.default_matmul_precision("highest"):
+        params = M.init_params(cfg, jax.random.PRNGKey(SEED))
+        solo = ServeEngine(cfg, params, **kw)
+        out_1 = solo.run(list(reqs))
+        ref = np.stack([out_1[r.rid] for r in reqs])
+        (ref_lg,) = forced_logits(solo, prompts, ref, params)
+        tp = ServeEngine(cfg, params, mesh=make_serve_mesh(TP_MESH), **kw)
+        del params, solo        # chip 0 then holds one shard, not the model
+        out_s = tp.run(list(reqs))
+        check_tokens(out_s, reqs, cfg.vocab_size)
+        got = np.stack([out_s[r.rid] for r in reqs])
+        got_lg, bad_lg = forced_logits(tp, prompts, ref, tp.params,
+                                       move_kv_heads(tp.params))
+    delta = logit_delta(got_lg, ref_lg)
+    fault = logit_delta(bad_lg, ref_lg)["rel_max_dlogit"]
+    agree = float(np.mean(got == ref))
+    report(f"{TP_ARCH} {CUT_LAYERS}-layer f32 cut tp={tp.tp} vs unsharded",
+           clock, t0, c0, greedy_token_agree=agree,
+           first_divergence=first_divergence(got, ref, ref_lg, got_lg),
+           fault_rel_max_dlogit=fault, **delta)
+    check(delta["rel_max_dlogit"] <= F32_LOGIT_RTOL,
+          f"TP={tp.tp} vs unsharded float32 logits: {delta}")
+    check(fault > F32_LOGIT_RTOL,
+          f"moved KV heads read {fault}, within the bound")
 
 
-def replica_phase(clock, n: int = 4, smoke: bool = False):
+def replica_phase(clock):
     """gemma3-4b behind the router, one replica per device."""
     import jax
     from repro.launch import serve
     from repro.serve import ServeEngine
     t0, c0 = time.perf_counter(), clock.seconds
-    run = serve.main(serve_args("gemma3-4b", "--replicas", str(n),
-                                smoke=smoke))
+    run = serve.main(serve_args(ARCH, "--replicas", str(REPLICAS)))
     reqs = requests(run.prompts, 64)
     got = {r.rid: run.tokens[i] for i, r in enumerate(reqs)}
     check_tokens(got, reqs, run.cfg.vocab_size)
@@ -284,7 +369,7 @@ def replica_phase(clock, n: int = 4, smoke: bool = False):
     ref = solo.run(list(reqs))
     same = all(np.array_equal(np.asarray(got[r.rid]), ref[r.rid])
                for r in reqs)
-    report(f"replicas gemma3-4b x{len(run.engines)}", clock, t0, c0,
+    report(f"replicas {ARCH} x{len(run.engines)}", clock, t0, c0,
            chunk=first.chunk, streams_match_one_engine=same)
     check(same, "replica token streams differ from one engine's")
 

@@ -17,8 +17,6 @@ boundaries. This kernel is the WA-evasion-spirited fix at decode scale
   KV head at once; the queries arrive packed per KV head outside the
   kernel, ``(Hkv, Sq·G, Dh)``, so every in-kernel value is a 2-D tile
   and Mosaic sees no reshape or transpose (it refuses them).
-* Dead blocks are also never fetched: their index maps clamp to a live
-  block, which the pipeline does not copy again.
 * Long caches shard over ``n_splits`` KV splits (flash-decoding): each
   split accumulates its own partial (m, l, acc) and a cross-split
   combine merges them outside the kernel.
@@ -44,10 +42,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-#: grid (batch, split, kv block): every axis but the innermost one
-#: writes disjoint output blocks
-_SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -112,18 +106,6 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o_ref[...] = acc_scr[...]
         m_ref[...] = m_scr[...]
         l_ref[...] = l_scr[...]
-
-
-def _live_block(blk, pos_b, *, bk, sq, window, last):
-    """Clamp a KV block index into the slot's live range.
-
-    Dead blocks are skipped by the kernel body; pointing their DMA at
-    an already-fetched live block lets the pipeline elide the copy, so
-    HBM traffic follows occupancy rather than the horizon.
-    """
-    hi = jnp.minimum((pos_b + (sq - 1)) // bk, last)
-    lo = 0 if window is None else jnp.maximum((pos_b - window + 1) // bk, 0)
-    return jnp.maximum(jnp.minimum(blk, hi), jnp.minimum(lo, hi))
 
 
 def _pack_q(q, hkv):
@@ -202,11 +184,9 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
         v = jnp.pad(v, padding)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     scale = 1.0 / math.sqrt(dh)
-    live = functools.partial(_live_block, bk=bk, sq=sq, window=window,
-                             last=n_splits * bps - 1)
 
     def kv_map(b_, s, ik, p):
-        return (b_, live(s * bps + ik, p[b_]), 0, 0)
+        return (b_, s * bps + ik, 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, bk=bk, bps=bps, sq=sq, g=g, hkv=hkv, scale=scale,
@@ -226,8 +206,6 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
         kernel,
         grid_spec=grid_spec,
         out_shape=_partials_shapes(n_splits, b, hkv, rows, dh),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_SEMANTICS),
         interpret=interpret)(pos_arr, _pack_q(q, hkv), k, v)
     o = combine_splits(o_part, m_part[..., 0], l_part[..., 0])
     return _unpack_o(o, sq).astype(q.dtype)
@@ -257,11 +235,11 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     page its logical block lives in — the gather *is* the block
     indexing, no materialized (B, NB*page, ...) cache ever exists.
 
-    The KV block equals the page size (one page per grid step). The
-    block-level early-out is unchanged: it tests
-    the *logical* block start against ``pos``, so out-of-order physical
-    tables cost nothing. Entries beyond a slot's live pages may be
-    arbitrary valid page ids (they are never read). Returns
+    The KV block equals the page size (one page per grid step) and the
+    block-level early-out is unchanged: it tests the *logical* block
+    start against ``pos``, so out-of-order physical tables cost
+    nothing. Entries beyond a slot's live pages may be arbitrary valid
+    page ids (they are fetched but fully masked). Returns
     (B, Sq, H, Dh) in q's dtype.
     """
     b, sq, h, dh = q.shape
@@ -280,11 +258,9 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
         bt = jnp.pad(bt, [(0, 0), (0, n_splits * bps - nb)])
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     scale = 1.0 / math.sqrt(dh)
-    live = functools.partial(_live_block, bk=ps, sq=sq, window=window,
-                             last=nb - 1)
 
     def kv_map(b_, s, ik, p, t):
-        return (t[b_, live(s * bps + ik, p[b_])], 0, 0, 0)
+        return (t[b_, s * bps + ik], 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, bk=ps, bps=bps, sq=sq, g=g, hkv=hkv,
@@ -304,8 +280,6 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=_partials_shapes(n_splits, b, hkv, rows, dh),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=_SEMANTICS),
         interpret=interpret)(pos_arr, bt, _pack_q(q, hkv), k_pages, v_pages)
     o = combine_splits(o_part, m_part[..., 0], l_part[..., 0])
     return _unpack_o(o, sq).astype(q.dtype)

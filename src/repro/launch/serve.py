@@ -92,6 +92,25 @@ def apply_runtime_policy(env: dict | None = None) -> dict:
     return applied
 
 
+def require_one_device_fit(params, device=None) -> None:
+    """Raise unless ``params`` alone fit one device's memory.
+
+    Chunk planning compiles the decode step unsharded, so a model that
+    needs its mesh to fit cannot be planned; the caller must pass an
+    explicit chunk. A device that reports no memory limit (the CPU) is
+    taken to fit.
+    """
+    device = device or jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    need = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    if limit and need >= limit:
+        raise ValueError(
+            f"cannot plan the decode chunk under a mesh: the planner "
+            f"compiles the step unsharded, and the parameters alone "
+            f"({need} B) exceed one {device.device_kind}'s {limit} B. "
+            f"Pass chunk= (--chunk N).")
+
+
 def generate(cfg, params, prompt_tokens, gen_len: int, *,
              temperature: float = 0.0, seed: int = 0,
              chunk: int | None = None, machine: str | None = None,
@@ -103,7 +122,9 @@ def generate(cfg, params, prompt_tokens, gen_len: int, *,
 
     One slot per prompt; the whole batch is admitted at once (a single
     batched prefill), then decoded in chunks. ``chunk=None`` plans the
-    chunk size analytically from the port model (repro.serve.planner).
+    chunk size analytically from the port model (repro.serve.planner);
+    under a mesh that needs the model to fit one device
+    (:func:`require_one_device_fit`).
     ``mesh`` shards every engine replica over the device mesh
     (params + KV over ``kvheads`` -> TP; ``None`` keeps the bit-exact
     single-device path); ``replicas > 1`` splits the batch across N
@@ -120,6 +141,8 @@ def generate(cfg, params, prompt_tokens, gen_len: int, *,
 
     b, s = prompt_tokens.shape
     if chunk is None and gen_len > 1:
+        if mesh is not None:
+            require_one_device_fit(params)
         from repro.serve.planner import plan_chunk_size
         chunk = plan_chunk_size(cfg, b, s + gen_len, machine=machine,
                                 max_chunk=min(32, gen_len - 1),

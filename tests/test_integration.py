@@ -60,6 +60,34 @@ def test_serve_generate_deterministic():
     assert t1.shape == (2, 8)
 
 
+class _Device:
+    device_kind = "test device"
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+@pytest.mark.parametrize("stats, raises", [
+    ({"bytes_limit": 4000}, True),      # 4000 B of params: no room
+    ({"bytes_limit": 4001}, False),
+    (None, False),                      # no limit reported (the CPU)
+])
+def test_mesh_planning_needs_params_on_one_device(stats, raises):
+    """The chunk planner compiles the decode step unsharded: under a mesh
+    a model whose parameters fill one device must get an explicit chunk,
+    with a message that says so, not an opaque compiler error."""
+    from repro.launch.serve import require_one_device_fit
+    params = {"w": np.zeros((10, 100), np.float32)}
+    if raises:
+        with pytest.raises(ValueError, match="--chunk"):
+            require_one_device_fit(params, _Device(stats))
+    else:
+        require_one_device_fit(params, _Device(stats))
+
+
 @pytest.mark.slow
 def test_mini_dryrun_subprocess(tmp_path):
     """Lower+compile a smoke config on a 2x2 placeholder mesh in a fresh
