@@ -6,13 +6,12 @@ Three measured claims, one per section of the overlapped runtime
 repro.serve.plandb):
 
 1. **Dispatch overlap** — with ``pipeline=2`` the engine enqueues round
-   N+1 while round N is still executing, so the host gap between
-   consecutive decode-dispatch *enqueues* shrinks and wall-clock
-   tokens/s rises. Gated on the container host for the dense engine
-   (gap reduction > 1 and tokens/s >= serial by the median of paired
-   interleaved repeats — robust to shared-host load noise);
+   N+1 while round N is still executing, so wall-clock tokens/s rises.
+   Gated on the container host for the dense engine (tokens/s >=
+   serial by the median of paired interleaved repeats — robust to
+   shared-host load noise);
    the paged engine is gated leniently (its per-round host work —
-   block-table assembly — is a larger fraction of the gap). Token
+   block-table assembly — is a larger fraction of a round). Token
    streams must be byte-identical between modes: the overlap is a
    scheduling change, never a numerics change.
 
@@ -66,19 +65,11 @@ def _requests(cfg, seed: int) -> list:
 
 
 def _run_once(eng, cfg, seed: int):
-    """One timed serve of a full batch; returns (wall_s, gap_s, results).
-
-    Gap counters are reset per run so each repeat measures its own mean
-    enqueue-to-enqueue gap (the engine accumulates across its life).
-    """
-    eng.dispatch_gap_s, eng.gap_rounds = 0.0, 0
-    eng._t_enqueued = None
+    """One timed serve of a full batch; returns (wall_s, results)."""
     reqs = _requests(cfg, seed)
     t0 = time.perf_counter()
     results = eng.run(reqs)
-    wall = time.perf_counter() - t0
-    gap = eng.stats()["mean_dispatch_gap_s"]
-    return wall, gap, results
+    return time.perf_counter() - t0, results
 
 
 def _measure_pair(engs: dict, cfg, repeats: int, seed: int) -> dict:
@@ -86,21 +77,16 @@ def _measure_pair(engs: dict, cfg, repeats: int, seed: int) -> dict:
     *interleaved* (serial, pipelined, serial, ...) so slow host-load
     drift hits both equally — back-to-back blocks let a load spike
     land entirely on one mode and flip the relative gate on noise.
-    Returns {mode: (min wall, median gap, results)} — the gap uses the
-    median across repeats because a min lets one lucky serial run
-    erase a stable ~15% reduction."""
+    Returns {mode: (min wall, results)}."""
     for eng in engs.values():                       # compile + warm caches
         _run_once(eng, cfg, seed)
     walls = {m: [] for m in engs}
-    gaps = {m: [] for m in engs}
     results = {}
     for _ in range(repeats):
         for mode, eng in engs.items():
-            w, g, results[mode] = _run_once(eng, cfg, seed)
+            w, results[mode] = _run_once(eng, cfg, seed)
             walls[mode].append(w)
-            gaps[mode].append(g)
-    out = {m: (min(walls[m]), sorted(gaps[m])[repeats // 2], results[m])
-           for m in engs}
+    out = {m: (min(walls[m]), results[m]) for m in engs}
     out["pair_speedups"] = sorted(
         ws / wp for ws, wp in zip(walls["serial"], walls["pipelined"]))
     return out
@@ -126,12 +112,11 @@ def _overlap_rows(cfg, params, repeats: int) -> list:
             # one measurement block; one independent re-measure with
             # doubled pairs must confirm before the gate fails
             runs = _measure_pair(engs, cfg, 2 * repeats, seed=7)
-        (w_s, g_s, r_s), (w_p, g_p, r_p) = runs["serial"], runs["pipelined"]
+        (w_s, r_s), (w_p, r_p) = runs["serial"], runs["pipelined"]
         pairs = runs["pair_speedups"]
         assert _stream_key(r_s) == _stream_key(r_p), \
             f"{kind}: pipelined token streams diverged from serial"
         tok_s, tok_p = SLOTS * GEN / w_s, SLOTS * GEN / w_p
-        gap_red = g_s / max(g_p, 1e-12)
         # the tokens/s gate uses the MEDIAN of the paired per-repeat
         # ratios: adjacent-in-time pairs cancel common-mode host load,
         # and the median tolerates a minority of polluted pairs — the
@@ -143,12 +128,8 @@ def _overlap_rows(cfg, params, repeats: int) -> list:
             f"slots={SLOTS};chunk={CHUNK};gen={GEN};repeats={repeats};"
             f"tok_s_serial={tok_s:.1f};tok_s_pipelined={tok_p:.1f};"
             f"speedup_median_paired={speedup:.3f};"
-            f"gap_serial_ms={g_s*1e3:.3f};"
-            f"gap_pipelined_ms={g_p*1e3:.3f};gap_reduction={gap_red:.2f};"
             f"streams=IDENTICAL")
         if kind == "dense":
-            assert gap_red > 1.0, \
-                f"dense: no dispatch-gap reduction ({gap_red:.2f}x)"
             assert speedup >= 1.0, \
                 f"dense: pipelined slower (median paired {speedup:.3f}x, " \
                 f"pairs {[round(p, 3) for p in pairs]})"

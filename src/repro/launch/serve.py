@@ -269,13 +269,12 @@ def main(argv=None) -> ServeRun:
     eng = eng_out[0]
     shard = f" tp={eng.tp}" if mesh is not None else ""
     repl = f" x{len(eng_out)} replicas" if len(eng_out) > 1 else ""
-    gap = eng.stats()["mean_dispatch_gap_s"]
     pipe = f" pipeline={eng.pipeline}" if eng.pipeline else ""
     print(f"generated {toks.shape} in {dt:.2f}s "
           f"({args.batch * args.gen / dt:.1f} tok/s) — "
           f"{eng.decode_dispatches} decode dispatches "
           f"(chunk={eng.chunk}) + {eng.prefill_dispatches} prefill"
-          f"{shard}{repl}{pipe} | mean dispatch gap {1e3 * gap:.2f}ms")
+          f"{shard}{repl}{pipe}")
     print("sample:", toks[0, :16].tolist())
     return ServeRun(cfg=cfg, params=params, prompts=prompts, tokens=toks,
                     engines=eng_out)

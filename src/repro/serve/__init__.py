@@ -37,8 +37,7 @@ admission controller with per-replica queues and backpressure.
 
 The overlapped runtime threads through all of it: ``pipeline=N`` on
 either engine double-buffers the decode dispatch (round N+1 enqueued
-while round N executes, token streams byte-identical to serial;
-``stats()['mean_dispatch_gap_s']`` is the measured host gap),
+while round N executes, token streams byte-identical to serial),
 ``repro.serve.staging`` prefetches queued prompts to the device so
 admission skips the H2D copy, and ``repro.serve.plandb`` persists an
 offline planner sweep (both backends, chunk x tile x tp x flavor) so

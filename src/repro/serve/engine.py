@@ -33,7 +33,6 @@ shared-prefix determinism also assumes dense FFNs).
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 
 import jax
@@ -47,6 +46,7 @@ from repro.serve import pages as pages_lib
 from repro.serve.decode import make_chunked_decode_step
 from repro.serve.planner import plan_chunk_size
 from repro.serve.slots import make_insert_step
+from repro.serve.spans import OFF, note, span
 from repro.serve.staging import PromptStager
 from repro.train import serve as serve_lib
 from repro.utils.sharding import (SERVE_ENGINE_RULES, mesh_axis_sizes,
@@ -139,14 +139,7 @@ class ServeEngine:
         self.pipeline = 2 if pipeline is True else max(0, int(pipeline))
         self._inflight: deque = deque()   # _InFlight records, oldest first
         self._tok_dev = None              # device (B,1) next-token feed
-        # measured dispatch gap: host seconds between consecutive decode
-        # dispatch *enqueues* (readback + bookkeeping between rounds).
-        # Serial rounds block on token readback inside that window;
-        # pipelined rounds only do host bookkeeping there — the delta is
-        # exactly what fig11 measures.
-        self.dispatch_gap_s = 0.0
-        self.gap_rounds = 0
-        self._t_enqueued: float | None = None
+        self._ok_dev = None               # device (B,) flags, last dispatch
         # async H2D prompt staging (repro.serve.staging): stage() ahead
         # of admission, admit() takes the already-resident array
         self.stager = PromptStager(depth=stage_depth, device=device)
@@ -301,9 +294,12 @@ class ServeEngine:
             self.cfg, cache_len=self.max_len,
             store_flavor=self.store_flavor)))
 
-    def _insert_prefilled(self, slot: int, one, prompt) -> None:
-        """Land one prefilled (batch-1) request cache in ``slot``."""
-        self.cache = self._insert(self.cache, one, jnp.int32(slot))
+    def _insert_prefilled(self, slot: int, one, prompt) -> int:
+        """Land one prefilled (batch-1) request cache in ``slot``;
+        returns the prompt tokens mapped from a prefix index (none)."""
+        with span("insert"):
+            self.cache = self._insert(self.cache, one, jnp.int32(slot))
+        return 0
 
     def _release_slot(self, i: int) -> None:
         """Retire slot ``i`` and free whatever it held."""
@@ -311,13 +307,6 @@ class ServeEngine:
 
     def _pre_dispatch(self) -> None:
         """Host-side bookkeeping before a chunk (no-op for dense slots)."""
-
-    def _mark_gap(self) -> None:
-        """Accumulate the host gap since the previous dispatch enqueue."""
-        now = time.perf_counter()
-        if self._t_enqueued is not None:
-            self.dispatch_gap_s += now - self._t_enqueued
-            self.gap_rounds += 1
 
     def _host_dev(self, arr):
         """Ship one mutable host array to device for a dispatch.
@@ -359,9 +348,9 @@ class ServeEngine:
         order. In pipelined mode the last-token slice becomes the next
         round's device-side token feed.
         """
-        self._mark_gap()
-        out = self._decode(*self._decode_args(), sub)
-        self._t_enqueued = time.perf_counter()
+        with span("dispatch", slots=self._n_active, chunk=self.chunk,
+                  ctx_tokens=self._ctx_tokens):
+            out = self._decode(*self._decode_args(), sub)
         if self.nonfinite_guard:
             toks, self.cache, _, ok = out
         else:
@@ -372,11 +361,21 @@ class ServeEngine:
         return toks, ok
 
     def _dispatch(self, sub):
-        """Issue one chunked decode over all slots; returns (B, chunk)."""
-        toks, ok = self._dispatch_raw(sub)
-        if ok is not None:
-            self._last_ok = np.asarray(ok)
+        """Issue one chunked decode over all slots; returns its (B,
+        chunk) tokens on the device, and keeps its per-slot finite
+        flags there for the readback."""
+        toks, self._ok_dev = self._dispatch_raw(sub)
         return toks
+
+    def _n_active(self) -> int:
+        return sum(s is not None for s in self.slots)
+
+    def _ctx_tokens(self) -> int:
+        return int(sum(int(self._pos[i]) for i, s in enumerate(self.slots)
+                       if s is not None))
+
+    def _held_tokens(self) -> int:
+        return sum(len(s.out) for s in self.slots if s is not None)
 
     # -- admission ----------------------------------------------------------
     def free_slots(self) -> list:
@@ -385,13 +384,14 @@ class ServeEngine:
 
     def _sample_first(self, logits):
         """First output token from the prefill's last-prompt-token logits."""
-        if self.temperature > 0.0:
-            self._key, sub = jax.random.split(self._key)
-            tok = jax.random.categorical(sub, logits / self.temperature,
-                                         axis=-1)
-        else:
-            tok = jnp.argmax(logits, axis=-1)
-        return np.asarray(tok, np.int32)
+        with span("first_token"):
+            if self.temperature > 0.0:
+                self._key, sub = jax.random.split(self._key)
+                tok = jax.random.categorical(sub, logits / self.temperature,
+                                             axis=-1)
+            else:
+                tok = jnp.argmax(logits, axis=-1)
+            return np.asarray(tok, np.int32)
 
     def _check_request(self, req: Request, prompt_len: int) -> None:
         if req.max_new_tokens < 1:
@@ -428,7 +428,11 @@ class ServeEngine:
         """
         if self.mesh is not None:
             return False
-        return self.stager.stage(req.rid, tuple(int(t) for t in req.prompt))
+        with span("stage", rid=req.rid, tokens=len(req.prompt)) as sp:
+            issued = self.stager.stage(req.rid,
+                                       tuple(int(t) for t in req.prompt))
+            note(sp, issued=int(issued))
+        return issued
 
     def admit(self, req: Request, slot: int | None = None) -> int:
         """Prefill one request and insert it into a free slot, in place.
@@ -446,23 +450,28 @@ class ServeEngine:
                 raise RuntimeError("no free slot")
             slot = free[0]
         assert self.slots[slot] is None, f"slot {slot} busy"
-        prompt = np.asarray(req.prompt, np.int32)
-        s = prompt.shape[0]
-        self._check_request(req, s)
-        prompt_t = tuple(int(t) for t in prompt)
-        tokens = prompt[None, :] if self.mesh is not None \
-            else self.stager.take(req.rid, prompt_t)
-        logits, one = self._prefill(self.params, {"tokens": tokens})
-        self.prefill_dispatches += 1
-        tok0 = int(self._sample_first(logits[:, -1])[0])
-        self._insert_prefilled(slot, one, prompt_t)
-        self.slots[slot] = _Slot(rid=req.rid, remaining=req.max_new_tokens - 1,
-                                 out=[tok0])
-        self._tok[slot, 0] = tok0
-        if self._tok_dev is not None:
-            # keep the chained device feed coherent with the host copy
-            self._tok_dev = self._tok_dev.at[slot, 0].set(tok0)
-        self._pos[slot] = s
+        with span("admit", rid=req.rid, slot=slot,
+                  prompt_tokens=len(req.prompt)) as sp:
+            prompt = np.asarray(req.prompt, np.int32)
+            s = prompt.shape[0]
+            self._check_request(req, s)
+            prompt_t = tuple(int(t) for t in prompt)
+            tokens = prompt[None, :] if self.mesh is not None \
+                else self.stager.take(req.rid, prompt_t)
+            with span("prefill", tokens=s):
+                logits, one = self._prefill(self.params, {"tokens": tokens})
+            self.prefill_dispatches += 1
+            tok0 = int(self._sample_first(logits[:, -1])[0])
+            hit = self._insert_prefilled(slot, one, prompt_t)
+            self.slots[slot] = _Slot(rid=req.rid,
+                                     remaining=req.max_new_tokens - 1,
+                                     out=[tok0])
+            self._tok[slot, 0] = tok0
+            if self._tok_dev is not None:
+                # keep the chained device feed coherent with the host copy
+                self._tok_dev = self._tok_dev.at[slot, 0].set(tok0)
+            self._pos[slot] = s
+            note(sp, prefix_hit_tokens=hit, emitted=1)
         return slot
 
     def admit_batch(self, reqs: list) -> None:
@@ -532,9 +541,16 @@ class ServeEngine:
         and admission timing are identical to the serial step, so token
         streams are byte-for-byte the same in both modes.
         """
-        if self.pipeline:
-            return self._step_pipelined()
-        return self._step_serial()
+        with span("decode") as sp:
+            held = self._held_tokens() if sp is not OFF else 0
+            q0 = len(self.quarantined)
+            retired = self._step_pipelined() if self.pipeline \
+                else self._step_serial()
+            note(sp, retired=len(retired), emitted=lambda: (
+                self._held_tokens() - held
+                + sum(len(t) for _, t in retired)
+                + sum(len(t) for _, t in self.quarantined[q0:])))
+        return retired
 
     def _step_serial(self) -> list:
         retired = []
@@ -549,7 +565,10 @@ class ServeEngine:
         self._key, sub = jax.random.split(self._key)
         toks = self._dispatch(sub)
         self.decode_dispatches += 1
-        toks = np.asarray(toks)
+        with span("readback"):
+            toks = np.asarray(toks)
+            if self._ok_dev is not None:
+                self._last_ok = np.asarray(self._ok_dev)
         for i, st in enumerate(self.slots):
             if st is None:
                 continue
@@ -635,8 +654,9 @@ class ServeEngine:
         (their token-0 self-feed output is garbage by construction).
         """
         rec = self._inflight.popleft()
-        toks = np.asarray(rec.toks)
-        oks = np.asarray(rec.ok) if rec.ok is not None else None
+        with span("readback"):
+            toks = np.asarray(rec.toks)
+            oks = np.asarray(rec.ok) if rec.ok is not None else None
         for i, st, take in rec.entries:
             if self.slots[i] is not st:
                 continue            # stream quarantined in an earlier round
@@ -662,22 +682,15 @@ class ServeEngine:
         self._tok_dev = None
 
     def stats(self) -> dict:
-        """Dispatch counters and the measured dispatch gap.
+        """Dispatch counters, rounds in flight and the stager's counts.
 
-        ``mean_dispatch_gap_s`` is the average host time between
-        consecutive decode-dispatch enqueues — the serial step blocks
-        on token readback inside that window, the pipelined step does
-        not, and the delta is the overlap win fig11 gates on.
+        Where the time between dispatches goes is read from a profiler
+        trace of the ``serve.*`` spans (``repro.serve.spans``).
         """
-        gap = self.dispatch_gap_s / self.gap_rounds if self.gap_rounds \
-            else 0.0
         return {"decode_dispatches": self.decode_dispatches,
                 "prefill_dispatches": self.prefill_dispatches,
                 "pipeline": self.pipeline,
                 "in_flight": len(self._inflight),
-                "dispatch_gap_s": self.dispatch_gap_s,
-                "gap_rounds": self.gap_rounds,
-                "mean_dispatch_gap_s": gap,
                 "staging": self.stager.stats()}
 
     def snapshot(self, checkpointer, step: int) -> bool:
@@ -805,25 +818,28 @@ class PagedServeEngine(ServeEngine):
         self.gather_pages = 0                 # live pages read, summed
                                               # over dispatches (fig8)
 
-    def _insert_prefilled(self, slot: int, one, prompt) -> None:
+    def _insert_prefilled(self, slot: int, one, prompt) -> int:
         ps = self.page_size
         s = len(prompt)
         npg = -(-s // ps)
-        shared = self.pool.match_prefix(prompt) if self.share_prefixes \
-            else []
-        fresh = self.pool.allocate(npg - len(shared))
-        held = list(shared) + list(fresh)
-        if self.share_prefixes:
-            # full prompt pages become matchable by later admissions
-            self.pool.register_prefix(prompt, held[:s // ps])
-        self.block_tables[slot, :] = -1
-        self.block_tables[slot, :npg] = held
-        # always dispatched: recurrent leaves need their slot row even
-        # when every KV page of the prompt is shared (zero page copies)
-        self.cache = self._page_insert(
-            self.cache, one, jnp.int32(slot),
-            jnp.asarray(np.asarray(fresh, np.int32)),
-            jnp.arange(len(shared), npg, dtype=jnp.int32))
+        with span("insert") as sp:
+            shared = self.pool.match_prefix(prompt) \
+                if self.share_prefixes else []
+            fresh = self.pool.allocate(npg - len(shared))
+            held = list(shared) + list(fresh)
+            if self.share_prefixes:
+                # full prompt pages become matchable by later admissions
+                self.pool.register_prefix(prompt, held[:s // ps])
+            self.block_tables[slot, :] = -1
+            self.block_tables[slot, :npg] = held
+            # always dispatched: recurrent leaves need their slot row
+            # even when every KV page of the prompt is shared
+            self.cache = self._page_insert(
+                self.cache, one, jnp.int32(slot),
+                jnp.asarray(np.asarray(fresh, np.int32)),
+                jnp.arange(len(shared), npg, dtype=jnp.int32))
+            note(sp, fresh_pages=len(fresh), shared_pages=len(shared))
+        return len(shared) * ps
 
     def _release_slot(self, i: int) -> None:
         held = [int(p) for p in self.block_tables[i] if p >= 0]
@@ -843,26 +859,31 @@ class PagedServeEngine(ServeEngine):
         scratch page — never an allocated shared one.
         """
         ps, pps = self.page_size, self.pages_per_slot
-        for i, st in enumerate(self.slots):
-            if st is None:
-                continue
-            p0 = int(self._pos[i])
-            take = min(self.chunk, st.remaining)
-            l_lo = min(p0 // ps, pps - 1)
-            l_hi = min((p0 + take - 1) // ps, pps - 1)
-            for lg in range(l_lo, l_hi + 1):
-                phys = int(self.block_tables[i, lg])
-                if phys < 0:
-                    self.block_tables[i, lg] = self.pool.allocate(1)[0]
+        allocated = copies = 0
+        with span("pre_dispatch") as sp:
+            for i, st in enumerate(self.slots):
+                if st is None:
                     continue
-                page, copied = self.pool.prepare_write(phys)
-                if copied:
-                    self.cache = self._page_copy(
-                        self.cache, jnp.int32(phys), jnp.int32(page))
-                self.block_tables[i, lg] = page
-        live = self.block_tables[[i for i, st in enumerate(self.slots)
-                                  if st is not None]]
-        self.gather_pages += int((live >= 0).sum())
+                p0 = int(self._pos[i])
+                take = min(self.chunk, st.remaining)
+                l_lo = min(p0 // ps, pps - 1)
+                l_hi = min((p0 + take - 1) // ps, pps - 1)
+                for lg in range(l_lo, l_hi + 1):
+                    phys = int(self.block_tables[i, lg])
+                    if phys < 0:
+                        self.block_tables[i, lg] = self.pool.allocate(1)[0]
+                        allocated += 1
+                        continue
+                    page, copied = self.pool.prepare_write(phys)
+                    if copied:
+                        self.cache = self._page_copy(
+                            self.cache, jnp.int32(phys), jnp.int32(page))
+                        copies += 1
+                    self.block_tables[i, lg] = page
+            live = self.block_tables[[i for i, st in enumerate(self.slots)
+                                      if st is not None]]
+            self.gather_pages += int((live >= 0).sum())
+            note(sp, pages_allocated=allocated, cow_copies=copies)
 
     def _decode_args(self):
         # ``bt`` is a fresh temporary (np.where allocates), so it may

@@ -445,8 +445,9 @@ class FaultTolerantRouter(ReplicaRouter):
                                     "round": self.round_idx,
                                     "tokens": int(len(merged))})
 
-    def step(self) -> list:
-        """One fault-aware round; advances the virtual clock.
+    def _round(self) -> list:
+        """One fault-aware round (:meth:`step`); advances the virtual
+        clock.
 
         Order per replica: health tick, deadline sweep, admissions
         (admissible states only — quarantined replicas drain), one

@@ -40,6 +40,8 @@ from collections import deque
 
 import numpy as np
 
+from repro.serve.spans import span
+
 
 class QueueFull(RuntimeError):
     """Raised by ``submit`` when the chosen replica's queue is full."""
@@ -114,16 +116,18 @@ class ReplicaRouter:
                 f"replica {i} queue full ({self.max_queue} waiting)")
             err.replica = i              # lets run() attribute the shed
             raise err
-        self.queues[i].append(req)
-        self._owner[req.rid] = i
-        self.submitted[i] += 1
-        # prefetch the prompt to the chosen replica's device while the
-        # request waits in queue (repro.serve.staging): admission then
-        # skips the H2D copy. Rescue replays resubmit through here, so
-        # rescued prompt+prefix streams are staged for free.
-        stage = getattr(self.replicas[i], "stage", None)
-        if stage is not None:
-            stage(req)
+        with span("submit", rid=req.rid, replica=i):
+            self.queues[i].append(req)
+            self._owner[req.rid] = i
+            self.submitted[i] += 1
+            # prefetch the prompt to the chosen replica's device while
+            # the request waits in queue (repro.serve.staging):
+            # admission then skips the H2D copy. Rescue replays
+            # resubmit through here, so rescued prompt+prefix streams
+            # are staged for free.
+            stage = getattr(self.replicas[i], "stage", None)
+            if stage is not None:
+                stage(req)
         return i
 
     def cancel(self, rid: str):
@@ -170,8 +174,21 @@ class ReplicaRouter:
         Per replica: pop queued requests into free slots (prefill +
         insert), then run one chunked decode round. Returns all
         requests retired this round as (rid, tokens) pairs, across
-        replicas.
+        replicas. The round is the ``serve.round`` span
+        (``repro.serve.spans``).
         """
+        with span("round", queued=self._queued, active=self._active):
+            return self._round()
+
+    def _queued(self) -> int:
+        return sum(len(q) for q in self.queues)
+
+    def _active(self) -> int:
+        return sum(s is not None for eng in self.replicas
+                   for s in eng.slots)
+
+    def _round(self) -> list:
+        """The work of one :meth:`step`."""
         retired = []
         for i, eng in enumerate(self.replicas):
             q = self.queues[i]
@@ -298,11 +315,8 @@ class ReplicaRouter:
         ``failed`` counts decode-round faults, ``retries`` the
         backoff-retried submits this replica refused, ``shed`` the
         requests dropped after the retry budget — all per replica, so
-        a sick replica is visible in one row. ``pipeline`` and
-        ``mean_dispatch_gap_s`` surface each replica's overlapped-
-        runtime state: the in-flight round bound (0 = serial) and the
-        measured mean host gap between decode-dispatch enqueues — the
-        number fig11 gates on, readable live mid-serve.
+        a sick replica is visible in one row. ``pipeline`` is the
+        replica's in-flight round bound (0 = serial).
         """
         return [{"replica": i,
                  "queued": len(self.queues[i]),
@@ -312,8 +326,5 @@ class ReplicaRouter:
                  "failed": self.failed[i],
                  "retries": self.retries[i],
                  "shed": self.shed[i],
-                 "pipeline": getattr(eng, "pipeline", 0),
-                 "mean_dispatch_gap_s": (
-                     eng.stats().get("mean_dispatch_gap_s", 0.0)
-                     if hasattr(eng, "stats") else 0.0)}
+                 "pipeline": getattr(eng, "pipeline", 0)}
                 for i, eng in enumerate(self.replicas)]

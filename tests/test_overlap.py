@@ -1,8 +1,8 @@
 """Overlapped serving runtime: pipelined decode dispatch is a pure
 scheduling change (token streams byte-identical to serial on dense and
-paged engines, through the router, at temperature 0 and >0), the
-dispatch-gap stats are measured, prompt staging hits/misses/falls back
-safely, and opportunistic snapshots never stall a decode round."""
+paged engines, through the router, at temperature 0 and >0), prompt
+staging hits/misses/falls back safely, and opportunistic snapshots
+never stall a decode round."""
 
 import os
 
@@ -95,7 +95,6 @@ def test_router_pipelined_identical(cfg, params):
             [Request(r.rid, r.prompt, r.max_new_tokens) for r in reqs]))
         for row in router.stats():
             assert row["pipeline"] == pipeline
-            assert row["mean_dispatch_gap_s"] >= 0.0
     assert out[0] == out[2]
 
 
@@ -118,20 +117,6 @@ def test_cancel_and_fork_sync_inflight(cfg, params):
                 rest[rid] = [int(x) for x in t]
         got[pipeline] = ([int(x) for x in toks], rest)
     assert got[0] == got[2]
-
-
-# -- dispatch-gap stats ---------------------------------------------------
-
-def test_dispatch_gap_measured(cfg, params):
-    eng = _engine(cfg, params, pipeline=2)
-    stats = eng.stats()
-    assert stats["gap_rounds"] == 0 and stats["mean_dispatch_gap_s"] == 0.0
-    eng.run(_requests(cfg, SLOTS))
-    stats = eng.stats()
-    assert stats["pipeline"] == 2
-    assert stats["gap_rounds"] > 0
-    assert stats["mean_dispatch_gap_s"] > 0.0
-    assert stats["in_flight"] == 0          # run() drains
 
 
 def test_serial_keeps_donation_pipelined_does_not(cfg, params):
