@@ -211,29 +211,46 @@ def flash_decode(q, k, v, pos, *, window: int | None = None,
     return _unpack_o(o, sq).astype(q.dtype)
 
 
-def _paged_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_scr, m_scr, l_scr, **kw):
-    """Paged grid step: the block table is consumed by the BlockSpec
-    index maps (physical page -> KV block), so the kernel body is the
-    dense one verbatim — masking stays in *logical* coordinates."""
-    del bt_ref
+def _paged_decode_kernel(pos_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr, **kw):
+    """Paged grid step: the block table and the layer index are consumed
+    by the BlockSpec index maps (layer, physical page -> KV block), so
+    the kernel body is the dense one verbatim — masking stays in
+    *logical* coordinates."""
+    del bt_ref, layer_ref
     _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_scr, m_scr, l_scr, **kw)
 
 
+def as_stacked(k_pages, v_pages, layer):
+    """Pools as ``(R, P, page, Hkv, Dh)`` plus the layer to read.
+
+    ``layer=None`` takes one layer's ``(P, page, Hkv, Dh)`` pool and
+    gives it a leading axis of 1 (a bitcast, no copy) at index 0.
+    """
+    if layer is None:
+        return k_pages[None], v_pages[None], jnp.zeros((), jnp.int32)
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32)
+
+
 def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
-                       window: int | None = None, n_splits: int = 1,
+                       layer=None, window: int | None = None,
+                       n_splits: int = 1,
                        interpret: bool = False) -> jax.Array:
     """Split-KV flash decode against a paged KV pool (vLLM-style).
 
-    q: (B, Sq, H, Dh); ``k_pages``/``v_pages``: (P, page, Hkv, Dh)
-    physical page pools shared by every slot; ``block_tables``: (B, NB)
-    int32 mapping each slot's logical page ``i`` (cache rows
-    ``i*page .. (i+1)*page-1``) to a physical page. Both the block
-    table and ``pos`` are scalar-prefetched: the KV BlockSpec index
-    maps read the table, so each grid step DMAs exactly the physical
-    page its logical block lives in — the gather *is* the block
-    indexing, no materialized (B, NB*page, ...) cache ever exists.
+    q: (B, Sq, H, Dh); ``k_pages``/``v_pages``: the layer-stacked
+    physical page pools (R, P, page, Hkv, Dh) shared by every slot,
+    read at layer ``layer`` (an int32 scalar, traced or not) — or, with
+    ``layer=None``, one layer's (P, page, Hkv, Dh) pools;
+    ``block_tables``: (B, NB) int32 mapping each slot's logical page
+    ``i`` (cache rows ``i*page .. (i+1)*page-1``) to a physical page.
+    The block table, ``pos`` and the layer are scalar-prefetched: the
+    KV BlockSpec index maps read them, so each grid step DMAs exactly
+    the physical page its logical block lives in, straight out of the
+    stacked pool — the gather *is* the block indexing, and neither a
+    materialized (B, NB*page, ...) cache nor a per-layer slice of the
+    pool ever exists.
 
     The KV block equals the page size (one page per grid step) and the
     block-level early-out is unchanged: it tests the *logical* block
@@ -242,8 +259,9 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     page ids (they are fetched but fully masked). Returns
     (B, Sq, H, Dh) in q's dtype.
     """
+    k_pages, v_pages, layer = as_stacked(k_pages, v_pages, layer)
     b, sq, h, dh = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    n_pages, ps, hkv = k_pages.shape[1:4]
     g = h // hkv
     assert h == hkv * g and sq >= 1
     rows = sq * g
@@ -251,7 +269,7 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     n_splits = max(1, min(n_splits, nb))
     bps = math.ceil(nb / n_splits)
     bt = jnp.asarray(block_tables, jnp.int32)
-    bt = jnp.clip(bt, 0, k_pages.shape[0] - 1)
+    bt = jnp.clip(bt, 0, n_pages - 1)
     if n_splits * bps > nb:
         # pad the table to the split grid; padded blocks are logically
         # past every pos (start >= nb*ps) so the early-out skips them
@@ -259,20 +277,20 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     scale = 1.0 / math.sqrt(dh)
 
-    def kv_map(b_, s, ik, p, t):
-        return (t[b_, s * bps + ik], 0, 0, 0)
+    def kv_map(b_, s, ik, p, t, lyr):
+        return (lyr[0], t[b_, s * bps + ik], 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel, bk=ps, bps=bps, sq=sq, g=g, hkv=hkv,
         scale=scale, window=window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_splits, bps),
         in_specs=[
             pl.BlockSpec((None, hkv, rows, dh),
-                         lambda b_, s, ik, p, t: (b_, 0, 0, 0)),
-            pl.BlockSpec((None, ps, hkv, dh), kv_map),
-            pl.BlockSpec((None, ps, hkv, dh), kv_map),
+                         lambda b_, s, ik, p, t, lyr: (b_, 0, 0, 0)),
+            pl.BlockSpec((None, None, ps, hkv, dh), kv_map),
+            pl.BlockSpec((None, None, ps, hkv, dh), kv_map),
         ],
         out_specs=_partials_specs(hkv, rows, dh),
         scratch_shapes=_scratch(hkv, rows, dh))
@@ -280,25 +298,27 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=_partials_shapes(n_splits, b, hkv, rows, dh),
-        interpret=interpret)(pos_arr, bt, _pack_q(q, hkv), k_pages, v_pages)
+        interpret=interpret)(pos_arr, bt, layer.reshape(1),
+                             _pack_q(q, hkv), k_pages, v_pages)
     o = combine_splits(o_part, m_part[..., 0], l_part[..., 0])
     return _unpack_o(o, sq).astype(q.dtype)
 
 
 def ref_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
-                     window: int | None = None) -> jax.Array:
+                     layer=None, window: int | None = None) -> jax.Array:
     """Pure-JAX paged twin of :func:`flash_decode_paged` (off-TPU path).
 
-    Gathers each slot's pages in logical order and delegates to the
-    dense reference decode. Because every logical row keeps its
-    position, masked rows contribute exact zeros and the result is
-    identical to decoding the equivalent contiguous cache.
+    Gathers each slot's pages of layer ``layer`` in logical order and
+    delegates to the dense reference decode. Because every logical row
+    keeps its position, masked rows contribute exact zeros and the
+    result is identical to decoding the equivalent contiguous cache.
     """
+    k_pages, v_pages, layer = as_stacked(k_pages, v_pages, layer)
     b = q.shape[0]
-    hkv, dh = k_pages.shape[2], k_pages.shape[3]
+    hkv, dh = k_pages.shape[3], k_pages.shape[4]
     bt = jnp.asarray(block_tables, jnp.int32)
-    k = k_pages[bt].reshape(b, -1, hkv, dh)
-    v = v_pages[bt].reshape(b, -1, hkv, dh)
+    k = k_pages[layer, bt].reshape(b, -1, hkv, dh)
+    v = v_pages[layer, bt].reshape(b, -1, hkv, dh)
     return ref_decode(q, k, v, pos, window=window)
 
 

@@ -26,31 +26,35 @@ from repro.kernels.attention import ref as R
 from repro.utils.sharding import current_mesh_rules, mesh_axis_sizes, spec_for
 
 
-def _per_shard(kernel, q, k, v, pos, *tables, paged: bool = False):
+def _per_shard(kernel, q, k, v, pos, *tables, layer=None):
     """Run a Pallas decode kernel on each device's own heads.
 
     GSPMD cannot partition a Mosaic kernel, so under an ambient mesh
     (``repro.utils.sharding.use_mesh_rules``) the call goes through
     ``shard_map``: heads split over the ``kvheads`` axes — whole GQA
     groups per shard (``validate_tp_heads``) — and slots over the
-    ``batch`` axes; page pools keep every page on every shard. Without
-    a mesh it is the plain call.
+    ``batch`` axes. A ``layer`` index marks the paged call: its pools
+    are layer-stacked ``(R, P, page, Hkv, Dh)`` and keep every layer
+    and page on every shard, and the index (replicated) is passed last.
+    Without a mesh it is the plain call.
     """
     b = q.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    extra = () if layer is None else (layer,)
     mesh, rules = current_mesh_rules()
     if mesh is None:
-        return kernel(q, k, v, pos, *tables)
+        return kernel(q, k, v, pos, *tables, *extra)
     from jax.sharding import PartitionSpec as P
     sizes = mesh_axis_sizes(mesh)
     bs = spec_for((b,), ("batch",), rules, sizes)[0]
-    hs = spec_for((k.shape[2],), ("kvheads",), rules, sizes)[0]
+    hs = spec_for((k.shape[-2],), ("kvheads",), rules, sizes)[0]
     qs = P(bs, None, hs, None)
-    kvs = P(None, None, hs, None) if paged else qs
-    in_specs = (qs, kvs, kvs, P(bs)) + (P(bs, None),) * len(tables)
+    kvs = qs if layer is None else P(None, None, None, hs, None)
+    in_specs = ((qs, kvs, kvs, P(bs)) + (P(bs, None),) * len(tables)
+                + (P(),) * len(extra))
     return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
                          out_specs=qs, check_vma=False)(q, k, v, pos,
-                                                        *tables)
+                                                        *tables, *extra)
 
 
 def validate_tp_heads(h: int, hkv: int, dh: int, tp: int, *,
@@ -160,11 +164,13 @@ def flash_decode(q, k, v, pos, *, window=None, impl="auto", bk=None,
 
 
 def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
-                       window=None, impl="auto", n_splits=None,
+                       layer=None, window=None, impl="auto", n_splits=None,
                        kv_len=None, machine=None):
     """Paged split-KV decode against a shared page pool, impl-routed.
 
-    q: (B, Sq, H, Dh); ``k_pages``/``v_pages``: (P, page, Hkv, Dh);
+    q: (B, Sq, H, Dh); ``k_pages``/``v_pages``: layer-stacked pools
+    (R, P, page, Hkv, Dh) read at layer ``layer`` (int32 scalar), or
+    one layer's (P, page, Hkv, Dh) with ``layer=None``;
     ``block_tables``: (B, NB) int32 (see
     ``kernels.attention.decode.flash_decode_paged``). ``kv_len`` bounds
     occupancy at *page* granularity: only the first
@@ -178,8 +184,9 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
     ``ref``/``auto``-off-TPU gather pages in logical order and run the
     dense oracle. Call under an enclosing ``jax.jit``.
     """
+    k_pages, v_pages, layer = D.as_stacked(k_pages, v_pages, layer)
     b, sq, h, dh = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    ps, hkv = k_pages.shape[2], k_pages.shape[3]
     nb = block_tables.shape[1]
     if kv_len is not None:
         nb_used = max(1, min(math.ceil(int(kv_len) / ps), nb))
@@ -191,11 +198,11 @@ def flash_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
                                        skv=nb * ps, dh=dh, h=h, hkv=hkv,
                                        batch=b, dtype=str(q.dtype))
             n_splits = plan.n_splits
-        def kernel(q, k, v, pos, bt):
-            return D.flash_decode_paged(q, k, v, bt, pos, window=window,
-                                        n_splits=n_splits,
+        def kernel(q, k, v, pos, bt, layer):
+            return D.flash_decode_paged(q, k, v, bt, pos, layer=layer,
+                                        window=window, n_splits=n_splits,
                                         interpret=interpret_mode())
         return _per_shard(kernel, q, k_pages, v_pages, pos, block_tables,
-                          paged=True)
+                          layer=layer)
     return D.ref_decode_paged(q, k_pages, v_pages, block_tables, pos,
-                              window=window)
+                              layer=layer, window=window)

@@ -138,7 +138,7 @@ def chunked_causal_attention(q, k, v, *, q_chunk: int = 512,
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int | None = None,
                      impl: str = "ref", kv_len: int | None = None,
-                     block_tables=None) -> jax.Array:
+                     block_tables=None, layer=None) -> jax.Array:
     """Single-token decode: q (B, 1, H, Dh) vs cache (B, Skv, Hkv, Dh).
 
     ``pos`` is the position of the new token — a scalar int32, or a (B,)
@@ -159,26 +159,30 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int | None = None,
     oracle it is validated against.
 
     ``block_tables`` switches to the paged cache layout: the caches are
-    physical page pools (P, page, Hkv, Dh) and ``block_tables`` (B, NB)
-    int32 maps each row's logical pages to physical ones
-    (repro.serve.pages). Routed impls run the scalar-prefetched paged
-    kernel (``ops.flash_decode_paged``); the inline ``"ref"`` path
-    gathers pages in logical order and falls through to the very same
-    dense computation below, so paged-vs-dense is bit-identical (masked
-    rows contribute exact zeros).
+    physical page pools, layer-stacked (R, P, page, Hkv, Dh) and read at
+    layer ``layer``, or one layer's (P, page, Hkv, Dh) with
+    ``layer=None``; ``block_tables`` (B, NB) int32 maps each row's
+    logical pages to physical ones (repro.serve.pages). Routed impls run
+    the scalar-prefetched paged kernel (``ops.flash_decode_paged``),
+    which reads the layer's pages where they lie in the stack; the
+    inline ``"ref"`` path gathers pages in logical order and falls
+    through to the very same dense computation below, so paged-vs-dense
+    is bit-identical (masked rows contribute exact zeros).
     """
     if block_tables is not None:
+        from repro.kernels.attention import decode as kdec
+        from repro.kernels.attention import ops as kops
         if impl != "ref" or kv_len is not None:
-            from repro.kernels.attention import ops as kops
             return kops.flash_decode_paged(q, k_cache, v_cache,
-                                           block_tables, pos,
+                                           block_tables, pos, layer=layer,
                                            window=window, impl=impl,
                                            kv_len=kv_len)
+        k_cache, v_cache, layer = kdec.as_stacked(k_cache, v_cache, layer)
         nb = q.shape[0]
-        hkv_p, dh_p = k_cache.shape[2], k_cache.shape[3]
+        hkv_p, dh_p = k_cache.shape[3], k_cache.shape[4]
         bt = jnp.asarray(block_tables, jnp.int32)
-        k_cache = k_cache[bt].reshape(nb, -1, hkv_p, dh_p)
-        v_cache = v_cache[bt].reshape(nb, -1, hkv_p, dh_p)
+        k_cache = k_cache[layer, bt].reshape(nb, -1, hkv_p, dh_p)
+        v_cache = v_cache[layer, bt].reshape(nb, -1, hkv_p, dh_p)
     elif impl != "ref" or kv_len is not None:
         from repro.kernels.attention import ops as kops
         return kops.flash_decode(q, k_cache, v_cache, pos, window=window,

@@ -330,9 +330,23 @@ def cache_defs(cfg: ModelConfig, batch: int, seq: int) -> dict:
     return out
 
 
+def _is_kv_def(d: ParamDef) -> bool:
+    """An attention KV leaf: its second logical axis is ``kv_seq``."""
+    return d.axes[1] == "kv_seq"
+
+
+def kv_leaf_flags(cfg: ModelConfig) -> dict:
+    """Cache-structured tree of bools: True on attention KV leaves.
+
+    These are the leaves the paged cache (repro.serve.pages) turns into
+    page pools; recurrent state (mamba/xLSTM) stays slot-batched.
+    """
+    return jax.tree.map(_is_kv_def, cache_defs(cfg, 1, 1), is_leaf=_is_def)
+
+
 def _cache_leaf_dtype(cfg, d: ParamDef):
     # recurrent states fp32; KV cache in param dtype
-    if d.axes[1] == "kv_seq":
+    if _is_kv_def(d):
         return jnp.dtype(cfg.param_dtype)
     return jnp.float32
 
@@ -388,7 +402,8 @@ def _project(x, w, b=None):
 def _attn_mixer(cfg: ModelConfig, p: dict, x, *, local: bool, mode: str,
                 positions, cache, pos, cache_len: int | None = None,
                 attn_impl: str | None = None, kv_len: int | None = None,
-                store_flavor: str | None = None, block_tables=None):
+                store_flavor: str | None = None, block_tables=None,
+                layer=None):
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_eff
     q = _project(x, p["wq"], p.get("bq"))
@@ -408,27 +423,33 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, *, local: bool, mode: str,
     new_cache = None
     flav = store_flavor or "standard"
     if mode == "decode" and block_tables is not None:
-        # paged cache: leaves are physical page pools (P, page, Hkv, Dh)
-        # shared across slots; scatter each slot's new row into the
-        # physical page its block table names for the current logical
-        # page. The engine guarantees every page in a chunk's write
-        # range is allocated and exclusively held (CoW already done),
-        # so the in-place scatter can never touch a shared page.
-        ps = cache["k"].shape[1]
+        # paged cache: leaves are physical page pools shared across
+        # slots — layer-stacked (R, P, page, Hkv, Dh) and addressed at
+        # ``layer``, or one layer's (P, page, Hkv, Dh) with layer=None.
+        # Scatter each slot's new row into the physical page its block
+        # table names for the current logical page; the pool is never
+        # sliced out of the stack. The engine guarantees every page in
+        # a chunk's write range is allocated and exclusively held (CoW
+        # already done), so the in-place scatter can never touch a
+        # shared page.
+        ps = cache["k"].shape[-3]
         nb = block_tables.shape[1]
         p1 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
         lp = jnp.minimum(p1 // ps, nb - 1)    # overshoot-retiring clamp
         phys = block_tables[jnp.arange(b), lp]
         row = p1 % ps
-        kc = cache["k"].at[phys, row].set(k[:, 0].astype(cache["k"].dtype))
-        vc = cache["v"].at[phys, row].set(v[:, 0].astype(cache["v"].dtype))
+        at = (phys, row) if layer is None else (layer, phys, row)
+        kc = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype))
+        vc = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))
         # page pools stay pool-resident with heads on TP (no-op unmeshed)
-        kc = sc(kc, None, None, "kvheads", None)
-        vc = sc(vc, None, None, "kvheads", None)
+        lead = (None,) * (kc.ndim - 2)
+        kc = sc(kc, *lead, "kvheads", None)
+        vc = sc(vc, *lead, "kvheads", None)
         y = attn_lib.decode_attention(q, kc, vc, pos, window=window,
                                       impl=attn_impl or "auto",
                                       kv_len=kv_len,
-                                      block_tables=block_tables)
+                                      block_tables=block_tables,
+                                      layer=layer)
         new_cache = {"k": kc, "v": vc}
     elif mode == "decode":
         # the in-place KV row writes route through the store-flavor door
@@ -494,8 +515,12 @@ def _slstm_mixer(cfg, p, x, *, mode, cache):
 def apply_block(cfg: ModelConfig, blk: str, p: dict, x, *, mode: str,
                 positions, cache, pos, cache_len: int | None = None,
                 attn_impl: str | None = None, kv_len: int | None = None,
-                store_flavor: str | None = None, block_tables=None):
-    """Returns (x_out, aux_loss, new_cache)."""
+                store_flavor: str | None = None, block_tables=None,
+                layer=None):
+    """Returns (x_out, aux_loss, new_cache).
+
+    ``layer`` indexes a layer-stacked paged KV pool (see ``forward``).
+    """
     mixer, ffn = blk.split(":")
     hx = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if mixer in ("attn", "attn_local"):
@@ -505,7 +530,7 @@ def apply_block(cfg: ModelConfig, blk: str, p: dict, x, *, mode: str,
                                    cache=cache, pos=pos, cache_len=cache_len,
                                    attn_impl=attn_impl, kv_len=kv_len,
                                    store_flavor=store_flavor,
-                                   block_tables=block_tables)
+                                   block_tables=block_tables, layer=layer)
     elif mixer == "mamba":
         y, new_cache = _mamba_mixer(cfg, p["mixer"], hx, mode=mode,
                                     cache=cache)
@@ -572,6 +597,9 @@ def forward(cfg: ModelConfig, params: dict, batch: dict, *,
     `block_tables` ((B, NB) int32, decode only) switches attention KV
     leaves to the paged layout: caches are physical page pools and each
     row's logical pages map through its table row (repro.serve.pages).
+    The scanned layers' pools stay layer-stacked and are carried through
+    the layer scan whole: each layer writes and reads them in place at
+    its own index.
     Returns logits (B, S, V) plus aux-loss scalar as (logits, aux[, cache]).
     """
     if cfg.embed_inputs:
@@ -629,35 +657,48 @@ def forward(cfg: ModelConfig, params: dict, batch: dict, *,
             lambda *xs: jnp.stack(xs), *new_slices_all)
     elif n_rep > 0:
         unit_blocks = [plan[j] for j in range(unit)]
+        # paged decode: the layer-stacked KV page pools ride in the scan
+        # carry and each layer addresses them by its index, so they stay
+        # in one buffer for the whole step — no per-layer slice of the
+        # pool, no restacked ys, no copy back into the caller's loop
+        # carry. Recurrent state stays in xs/ys (slot-batched, small).
+        pools, layers = {}, None
+        if mode == "decode" and block_tables is not None:
+            kv = kv_leaf_flags(cfg)["scan"]
+            pools = {j: c for j, c in cache["scan"].items()
+                     if all(jax.tree.leaves(kv[j]))}
+            layers = jnp.arange(n_rep, dtype=jnp.int32)
+        rest = {j: c for j, c in cache["scan"].items() if j not in pools} \
+            if mode == "decode" else {}
 
-        def unit_body(x_aux, xs):
-            x, aux = x_aux
-            p_slice, c_slice = xs
-            new_slices = {}
+        def unit_body(carry, xs):
+            x, aux, pools = carry
+            p_slice, c_slice, layer = xs
+            pools, new_slices = dict(pools), {}
             for j, blk in enumerate(unit_blocks):
-                cj = c_slice[str(j)] if c_slice is not None else None
-                x, a, nc = apply_block(cfg, blk, p_slice[str(j)], x,
+                key = str(j)
+                pooled = key in pools
+                x, a, nc = apply_block(cfg, blk, p_slice[key], x,
                                        mode=mode, positions=positions,
-                                       cache=cj, pos=pos,
+                                       cache=pools[key] if pooled
+                                       else c_slice.get(key), pos=pos,
                                        cache_len=cache_len,
                                        attn_impl=attn_impl, kv_len=kv_len,
                                        store_flavor=store_flavor,
-                                       block_tables=block_tables)
+                                       block_tables=block_tables,
+                                       layer=layer if pooled else None)
                 aux = aux + a
-                if nc is not None:
-                    new_slices[str(j)] = nc
-            return (x, aux), (new_slices if new_slices else None)
+                if pooled:
+                    pools[key] = nc
+                elif nc is not None:
+                    new_slices[key] = nc
+            return (x, aux, pools), (new_slices if new_slices else None)
 
         body = _remat_wrap(cfg, unit_body)
-        if mode == "decode":
-            xs = (params["scan"], cache["scan"])
-        elif mode == "prefill":
-            xs = (params["scan"], None)
-        else:
-            xs = (params["scan"], None)
-        (x, aux_total), scan_caches = jax.lax.scan(body, (x, aux_total), xs)
-        if mode in ("prefill", "decode") and scan_caches is not None:
-            new_cache["scan"] = scan_caches
+        (x, aux_total, pools), scan_caches = jax.lax.scan(
+            body, (x, aux_total, pools), (params["scan"], rest, layers))
+        if mode in ("prefill", "decode") and (scan_caches or pools):
+            new_cache["scan"] = {**(scan_caches or {}), **pools}
 
     for i in range(n_tail):
         blk = plan[n_rep * unit + i]

@@ -5,9 +5,12 @@ Generalizes ``train/serve.py:make_decode_loop_step`` (greedy, scalar
 position) to the serve engine's needs: every slot decodes at its own
 position (``pos`` is a (B,) vector), and sampling happens inside the
 token scan so a temperature>0 engine still issues one dispatch per
-chunk. The cache flows through the scan carry, so with the jit-level
-donation the per-token dynamic-update-slice stays in place — the
-NT-store analogue (DESIGN.md §2) at serve scale.
+chunk. The cache flows through the scan carry and is donated at the
+jit level. With ``paged=True`` the page pools stay in one buffer for
+the whole chunk: the model's layer scan carries the layer-stacked pools
+and writes each new row in place (``models.model.forward``), so no
+token copies a pool. The dense step's layer scan still takes the cache
+as ``xs`` and returns it as ``ys``, which need not stay in place.
 """
 
 from __future__ import annotations

@@ -40,25 +40,13 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
+from repro.models.model import kv_leaf_flags
 from repro.serve.slots import SLOT_AXIS
 
 
 def pages_per_slot(max_len: int, page_size: int) -> int:
     """Block-table width: logical pages covering the decode horizon."""
     return math.ceil(max_len / page_size)
-
-
-def kv_leaf_flags(cfg: ModelConfig) -> dict:
-    """Cache-structured tree of bools: True on paged (KV) leaves.
-
-    KV leaves are identified the same way the cache dtype rule does it
-    (``models.model``): their second logical axis is ``kv_seq``.
-    Recurrent state (mamba/xLSTM) stays slot-batched — only attention
-    KV is paged.
-    """
-    defs = M.cache_defs(cfg, 1, 1)
-    return jax.tree.map(lambda d: d.axes[1] == "kv_seq", defs,
-                        is_leaf=lambda x: isinstance(x, M.ParamDef))
 
 
 def paged_cache_shapes(cfg: ModelConfig, max_slots: int, n_pages: int,

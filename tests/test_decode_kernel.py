@@ -193,6 +193,35 @@ def test_paged_ops_routing_and_page_bound():
                                        err_msg=f"{impl}/{kv_len}")
 
 
+@pytest.mark.parametrize("layer", [1, 2])
+def test_flash_decode_paged_reads_its_layer_of_the_stack(layer):
+    """Layer-stacked pools (R, P, page, Hkv, Dh): the kernel, its ref
+    twin and the routed entry read the pages of layer ``layer`` where
+    they lie, and nothing of the other layers (which hold garbage)."""
+    q, k, v = _rand_case(2, 64, 8, 2, 32, seed=4)
+    pos = jnp.asarray([20, 39], jnp.int32)
+    kp, vp, bt = _paginate(k, v, 8)
+    rng = np.random.default_rng(3)
+    ks = rng.standard_normal((3,) + kp.shape).astype(np.float32)
+    vs = rng.standard_normal((3,) + vp.shape).astype(np.float32)
+    ks[layer], vs[layer] = kp, vp
+    ks, vs, lyr = jnp.asarray(ks), jnp.asarray(vs), jnp.int32(layer)
+    ref = A.decode_attention(q, k, v, pos)
+    for ns in (1, 2):
+        got = D.flash_decode_paged(q, ks, vs, bt, pos, layer=lyr,
+                                   n_splits=ns, interpret=True)
+        np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    got = D.ref_decode_paged(q, ks, vs, bt, pos, layer=lyr)
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+    for impl in ("ref", "pallas"):
+        got = kops.flash_decode_paged(q, ks, vs, bt, pos, layer=lyr,
+                                      impl=impl, kv_len=40)
+        np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5,
+                                   err_msg=impl)
+    got = A.decode_attention(q, ks, vs, pos, block_tables=bt, layer=lyr)
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
 def test_paged_dead_table_columns_are_masked():
     """Columns past a slot's live pages may hold *any* valid page id
     (the engine maps them to the scratch page; recycled tables may
