@@ -47,8 +47,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    import serving
-    import weights as W
+    import run as R
     from repro.models import model as M
     from repro.serve import pages as P
     from repro.serve.decode import make_chunked_decode_step
@@ -63,8 +62,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     with open(args.config) as f:
         conf = json.load(f)
-    m = W.Model.from_config(conf)
-    cfg = serving.program_config(m, conf["name"])
+    cfg = R.model(conf).program_config(conf["name"])
     e = conf["engine"]
     n_pages = args.n_pages or e["n_pages"]
     topo = topologies.get_topology_desc(platform="tpu",

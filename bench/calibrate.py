@@ -70,8 +70,8 @@ def main(argv=None) -> int:
     import arrivals
     import serving
     R.use_compile_cache()
-    vocab = conf["model"]["vocab_size"]
-    server = serving.Server(conf, args.seeds[0])
+    server = serving.Server(R.model(conf), conf, args.seeds[0])
+    vocab = server.m.vocab
     server.warm_up(arrivals.open_loop(mix, args.seeds[0], args.seconds,
                                       vocab),
                    np.random.default_rng(args.seeds[0]))

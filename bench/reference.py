@@ -1,12 +1,14 @@
-"""Plain float32 reference of a Llama-style decoder, layer by layer.
+"""Plain float32 reference of a decoder, layer by layer.
 
-RMSNorm, rotary position embedding (half-split pairs, theta from the
-configuration), grouped-query causal attention with the query, key and
-value biases where the configuration has them, and a SwiGLU feed
-forward; no cache, no kernels, no batching of requests. It imports
-nothing of the program: the weights come from ``weights.py`` and the
-seed. Every matrix product runs at ``Precision.HIGHEST``, because a
-float32 product on a TPU otherwise runs in bfloat16 passes.
+The shared primitives are here: linear layers, RMSNorm, rotary position
+embedding (half-split pairs) and grouped-query causal attention; each
+architecture file (``arch/<name>.py``) builds its own layers from them
+(and its embedding and head, where ``Decoder``'s do not serve), and
+this module runs them: no cache, no kernels, no
+batching of requests. It imports nothing of the program: the weights
+come from ``weights.py`` and the seed. Every matrix product runs at
+``Precision.HIGHEST``, because a float32 product on a TPU otherwise
+runs in bfloat16 passes.
 
 ``control=True`` computes the same forward in float8 (e4m3): every
 linear layer's weights and inputs are scaled to the format's range,
@@ -84,54 +86,71 @@ def _attention(q, k, v):
     return out.reshape(s, h, dh)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "control"))
-def _block(x, lw, m: W.Model, control: bool):
-    """One decoder layer over one padded sequence x (S, d) float32."""
+class Decoder:
+    """What most decoders share, for an architecture's ``Model`` to
+    inherit and override where it differs: the top leaves (token
+    embedding, final RMSNorm, output head) and their reference, and the
+    counts' sizes of the layers that attend over the paged cache, those
+    of kind "attn". Reads ``d``, ``vocab``, ``eps``, ``heads``,
+    ``kv_heads``, ``head_dim`` and ``dtype``."""
+
+    def top_leaves(self) -> list:
+        return [("embed", (self.vocab, self.d), 1.0),
+                ("final_norm", (self.d,), "norm"),
+                ("lm_head", (self.d, self.vocab), self.d ** -0.5)]
+
+    def ref_embed(self, top: dict, ids, control: bool):
+        return top["embed"][ids].astype(jnp.float32)
+
+    def ref_head(self, top: dict, x, control: bool):
+        h = _rms(x, top["final_norm"].astype(jnp.float32), self.eps)
+        return _linear(h, top["lm_head"].astype(jnp.float32), control)
+
+    @property
+    def cached_layers(self) -> int:
+        """Layers that read the paged cache."""
+        return W.kinds(self).count("attn")
+
+    @property
+    def attn_width(self) -> int:
+        """Query heads times head size, in a layer that attends."""
+        return self.heads * self.head_dim
+
+    @property
+    def kv_row_bytes(self) -> int:
+        """K and V of one cached row in one layer."""
+        return 2 * self.kv_heads * self.head_dim * \
+            jnp.dtype(self.dtype).itemsize
+
+
+@functools.partial(jax.jit, static_argnames=("m", "kind", "control"))
+def _layer(x, lw, m, kind: str, control: bool):
+    """One layer of the given kind over one padded sequence x (S, d)."""
     f = {k: v.astype(jnp.float32) for k, v in lw.items()}
-    s = x.shape[0]
-    pos = jnp.arange(s)
-    h = _rms(x, f["ln1"], m.eps)
+    return m.ref_layer(kind, x, f, control)
 
-    def proj(name, heads):
-        y = _linear(h, f[name].reshape(m.d, heads * m.head_dim), control)
-        y = y.reshape(s, heads, m.head_dim)
-        bias = {"wq": "bq", "wk": "bk", "wv": "bv"}[name]
-        return y + f[bias] if bias in f else y
 
-    q = _rope(proj("wq", m.heads), pos, m.rope_theta)
-    k = _rope(proj("wk", m.kv_heads), pos, m.rope_theta)
-    v = proj("wv", m.kv_heads)
-    o = _attention(q, k, v).reshape(s, m.heads * m.head_dim)
-    x = x + _linear(o, f["wo"].reshape(m.heads * m.head_dim, m.d), control)
-    h = _rms(x, f["ln2"], m.eps)
-    g = _linear(h, f["w_gate"], control)
-    u = _linear(h, f["w_up"], control)
-    return x + _linear(jax.nn.silu(g) * u, f["w_down"], control)
+@functools.partial(jax.jit, static_argnames=("m", "kind"))
+def _layer_weights(kd, i, m, kind: str):
+    return W.layer(m, kd, i, kind)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
-def _layer_weights(kd, i, m: W.Model):
-    return W.layer(m, kd, i)
-
-
-@functools.partial(jax.jit, static_argnames=("m",))
-def _top_weights(kd, m: W.Model):
+def _top_weights(kd, m):
     return W.top(m, kd)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "control"))
-def _embed(emb, ids, m: W.Model, control: bool):
-    del control
-    return emb[ids].astype(jnp.float32)
+def _embed(top, ids, m, control: bool):
+    return m.ref_embed(top, ids, control)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "control"))
-def _head(x, norm, head, m: W.Model, control: bool):
-    h = _rms(x, norm.astype(jnp.float32), m.eps)
-    return _linear(h, head.astype(jnp.float32), control)
+def _head(top, x, m, control: bool):
+    return m.ref_head(top, x, control)
 
 
-def logits(m: W.Model, seed: int, seqs: list, wanted: list,
+def logits(m, seed: int, seqs: list, wanted: list,
            control: bool = False) -> list:
     """Logits (n_i, V) float32 at positions ``wanted[i]`` of ``seqs[i]``.
 
@@ -145,10 +164,10 @@ def logits(m: W.Model, seed: int, seqs: list, wanted: list,
         n = len(ids)
         padded = np.zeros(-(-n // PAD) * PAD, np.int32)
         padded[:n] = ids
-        xs.append(_embed(top["embed"], jnp.asarray(padded), m, control))
-    for i in range(m.layers):
-        lw = _layer_weights(kd, jnp.int32(i), m)
-        xs = [_block(x, lw, m, control) for x in xs]
+        xs.append(_embed(top, jnp.asarray(padded), m, control))
+    for i, kind in enumerate(W.kinds(m)):
+        lw = _layer_weights(kd, jnp.int32(i), m, kind)
+        xs = [_layer(x, lw, m, kind, control) for x in xs]
         del lw
     out = []
     for x, w in zip(xs, wanted):
@@ -157,8 +176,7 @@ def logits(m: W.Model, seed: int, seqs: list, wanted: list,
         w = np.asarray(w, np.int32)
         at = np.full(-(-len(w) // PAD) * PAD, w[-1], np.int32)
         at[:len(w)] = w
-        out.append(_head(x[jnp.asarray(at)], top["final_norm"],
-                         top["lm_head"], m, control)[:len(w)])
+        out.append(_head(top, x[jnp.asarray(at)], m, control)[:len(w)])
     return out
 
 

@@ -14,8 +14,10 @@ was correct, and the device. Without a TPU, or with fewer chips than
 the cell asks for, it exits non-zero and prints no result.
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration file, its traffic file ``bench/traffic/<mix>.json`` and
-one reader ``bench/metrics/<metric>.py`` per metric.
+configuration file, the architecture file ``bench/arch/<name>.py`` that
+the configuration's ``model.architectures`` names, its traffic file
+``bench/traffic/<mix>.json`` and one reader ``bench/metrics/<metric>.py``
+per metric.
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ T0 = time.time()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -70,12 +73,38 @@ def metrics_of(bench: dict, cell: str, trace: bool) -> list:
     return [m for m in group if cell in m.get("workloads", [cell])]
 
 
+def load_module(path: str, name: str):
+    """The Python file at ``path`` as module ``name``, loaded afresh."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod           # where a dataclass looks itself up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str):
     path = os.path.join(BENCH, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(path, f"metric_{name}").read
+
+
+def model(conf: dict):
+    """The configuration's sizes as its architecture's ``Model``, from
+    ``bench/arch/<name>.py``: ``<name>`` is ``model.architectures[0]``
+    lower-cased, less ``ForCausalLM``."""
+    arch = conf["model"]["architectures"][0]
+    name = arch.removesuffix("ForCausalLM").lower()
+    path = os.path.join(BENCH, "arch", name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"bench: no architecture file {path} for {arch!r}")
+    return architecture(path).Model.from_config(conf)
+
+
+@functools.cache
+def architecture(path: str):
+    """The architecture file at ``path``, loaded once per process: one
+    ``Model`` class, so the reference's jitted calls, static in the
+    model, compile once."""
+    return load_module(path, "arch_" + os.path.basename(path)[:-3])
 
 
 # -- what happened in the window ----------------------------------------------
@@ -163,7 +192,7 @@ class Run:
     cell: dict
     conf: dict
     mix: dict
-    m: object                 # weights.Model
+    m: object                 # the architecture's Model
     chips: int
     seconds: float            # the measured window, as asked
     setup_s: float
@@ -356,9 +385,9 @@ def run_cell(args, bench: dict, out=sys.stdout, err=sys.stderr) -> dict:
     jax.monitoring.register_event_duration_secs_listener(counter)
     kind = devs[0].device_kind
     peak = peaks.peak(kind)
-    reqs = arrivals.open_loop(mix, args.seed, args.seconds,
-                              conf["model"]["vocab_size"])
-    server = serving.Server(conf, args.seed)
+    m = model(conf)
+    reqs = arrivals.open_loop(mix, args.seed, args.seconds, m.vocab)
+    server = serving.Server(m, conf, args.seed)
     server.warm_up(reqs, np.random.default_rng([int(args.seed), 2]))
     rec = Recorder(server, server.chunk)
     annotate = jax.profiler.TraceAnnotation
@@ -388,7 +417,6 @@ def run_cell(args, bench: dict, out=sys.stdout, err=sys.stderr) -> dict:
     drain_end = time.perf_counter() - t0
     gc.unfreeze()
     mem = serving.memory_peak_bytes()
-    m = server.m
     server.close()
     del server
     run = Run(cell=cell, conf=conf, mix=mix, m=m, chips=cell["chips"],

@@ -1,11 +1,11 @@
 """The benchmark's one adapter to the serving program.
 
-Everything the harness knows of the program's internals is here: how a
-configuration file becomes the program's ``ModelConfig`` and parameter
-tree, which engine and router serve it, and how emitted tokens are read
-after each router round (the retired list, plus each engine slot's
-request id and ``len(out)``), since the engine has no token-emission
-event of its own.
+Everything the harness knows of the program's internals is here: which
+engine and router serve a model, and how emitted tokens are read after
+each router round (the retired list, plus each engine slot's request id
+and ``len(out)``), since the engine has no token-emission event of its
+own. How a configuration becomes the program's ``ModelConfig`` and
+parameter tree is its architecture's (``arch/<name>.py``).
 """
 
 from __future__ import annotations
@@ -21,35 +21,16 @@ import weights as W
 QUEUE_BOUND = 1 << 30
 
 
-def program_config(m: W.Model, name: str):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(name=name, family="dense", n_layers=m.layers,
-                       d_model=m.d, n_heads=m.heads, n_kv_heads=m.kv_heads,
-                       head_dim=m.head_dim, d_ff=m.ffn, vocab_size=m.vocab,
-                       qkv_bias=m.qkv_bias, rope_theta=m.rope_theta,
-                       norm_eps=m.eps, param_dtype=m.dtype)
-
-
-def program_tree(top: dict, layers: dict) -> dict:
-    """The program's parameter tree (``repro.models.model``) from the
-    benchmark's leaves: one scanned block repeated over every layer."""
-    mixer = {k: layers[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
-             if k in layers}
-    block = {"ln1": layers["ln1"], "mixer": mixer, "ln2": layers["ln2"],
-             "ffn": {k: layers[k] for k in ("w_gate", "w_up", "w_down")}}
-    return {"tok_embed": top["embed"], "scan": {"0": block}, "tail": {},
-            "final_norm": top["final_norm"], "lm_head": top["lm_head"]}
-
-
 class Server:
     """One ``PagedServeEngine`` on one chip behind a ``ReplicaRouter``,
-    as the configuration file's ``engine`` entry describes."""
+    serving the model ``m`` as the configuration file's ``engine`` entry
+    describes."""
 
-    def __init__(self, conf: dict, seed: int):
+    def __init__(self, m, conf: dict, seed: int):
         from repro.models import model as M
         from repro.serve import PagedServeEngine, ReplicaRouter
-        self.m = W.Model.from_config(conf)
-        self.cfg = program_config(self.m, conf["name"])
+        self.m = m
+        self.cfg = m.program_config(conf["name"])
         e = conf["engine"]
         self.page_size, self.chunk = e["page_size"], e["chunk"]
         self._make = self._param_maker(M)
@@ -65,14 +46,14 @@ class Server:
         """One jitted call: the seed's weights in the program's tree."""
         m = self.m
         want = M.param_shapes(self.cfg)
-        got = jax.eval_shape(lambda kd: program_tree(*W.stacked(m, kd)),
+        got = jax.eval_shape(lambda kd: m.program_tree(*W.stacked(m, kd)),
                              jax.ShapeDtypeStruct((2,), np.uint32))
         if jax.tree.structure(want) != jax.tree.structure(got) or any(
                 (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
                 zip(jax.tree.leaves(want), jax.tree.leaves(got))):
             raise ValueError("benchmark weights do not match the program's "
                              "parameter tree")
-        return jax.jit(lambda kd: program_tree(*W.stacked(m, kd)))
+        return jax.jit(lambda kd: m.program_tree(*W.stacked(m, kd)))
 
     # -- the entry the window drives ----------------------------------------
     def submit(self, req) -> None:

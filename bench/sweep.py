@@ -43,8 +43,8 @@ def main(argv=None) -> int:
     import serving
     from percentile import p90
     R.use_compile_cache()
-    vocab = conf["model"]["vocab_size"]
-    server = serving.Server(conf, args.seed)
+    server = serving.Server(R.model(conf), conf, args.seed)
+    vocab = server.m.vocab
     first = arrivals.open_loop(mix, args.seed, args.seconds, vocab)
     server.warm_up(first, np.random.default_rng(args.seed))
     R.settle_heap()

@@ -23,7 +23,8 @@ def smoke_conf() -> dict:
     """A Llama-style decoder far too small to mean anything on a chip."""
     return {
         "name": "smoke", "source": "toy sizes for CPU tests",
-        "model": {"hidden_size": 128, "intermediate_size": 256,
+        "model": {"architectures": ["LlamaForCausalLM"],
+                  "hidden_size": 128, "intermediate_size": 256,
                   "num_attention_heads": 4, "num_key_value_heads": 2,
                   "num_hidden_layers": 2, "vocab_size": 512,
                   "rope_theta": 10000.0, "rms_norm_eps": 1e-6,

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import reference as R
-import serving
+import run
 import weights as W
 from conftest import smoke_conf
 
@@ -19,14 +19,15 @@ SEED = 2**31 + 11
 
 @pytest.fixture(scope="module")
 def model():
-    return W.Model.from_config(smoke_conf())
+    return run.model(smoke_conf())
 
 
 def test_stacked_weights_are_each_layers_own(model):
     kd = jnp.asarray(W.key_data(SEED))
     top, layers = jax.jit(lambda k: W.stacked(model, k))(kd)
+    layers = layers["scan"]["0"]
     for i in range(model.layers):
-        one = W.layer(model, kd, i)
+        one = W.layer(model, kd, i, "attn")
         for k, v in one.items():
             assert np.array_equal(np.asarray(layers[k][i]), np.asarray(v)), k
     assert np.array_equal(np.asarray(top["embed"]),
@@ -38,9 +39,9 @@ def test_stacked_weights_are_each_layers_own(model):
 def test_reference_matches_the_program_forward(model):
     from repro.models import model as M
     m32 = dataclasses.replace(model, dtype="float32")
-    cfg = serving.program_config(m32, "smoke")
+    cfg = m32.program_config("smoke")
     kd = jnp.asarray(W.key_data(SEED))
-    tree = serving.program_tree(*W.stacked(model, kd))
+    tree = model.program_tree(*W.stacked(model, kd))
     tree = jax.tree.map(lambda a: a.astype(jnp.float32), tree)
     ids = np.random.default_rng(0).integers(0, model.vocab, 40)
     with jax.default_matmul_precision("highest"):

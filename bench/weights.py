@@ -1,4 +1,11 @@
-"""Model sizes from a configuration file, and seeded weights.
+"""Seeded weights of any architecture.
+
+An architecture's ``Model`` (``arch/<name>.py``) lists the leaves of
+each kind of layer and the top leaves, each as ``(name, shape, scale)``
+or ``(name, shape, scale, dtype)``: the scale is the draw's standard
+deviation, or "norm" for a norm weight (1 +- a tenth), and the type is
+the model's unless the leaf names its own. ``Model.pattern`` is the
+kinds, cycled over the layers (``kinds``).
 
 The weights depend only on the seed, the layer and the leaf: the
 benchmark makes the whole stacked set on the device in one jitted call
@@ -10,67 +17,11 @@ multiply, so the same bits come out of either call.
 
 from __future__ import annotations
 
-import dataclasses
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-
-@dataclasses.dataclass(frozen=True)
-class Model:
-    """A Llama-style decoder's sizes, read from a config's ``model``."""
-
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    ffn: int
-    vocab: int
-    qkv_bias: bool
-    rope_theta: float
-    eps: float
-    dtype: str
-
-    @classmethod
-    def from_config(cls, conf: dict) -> "Model":
-        m = conf["model"]
-        return cls(layers=m["num_hidden_layers"], d=m["hidden_size"],
-                   heads=m["num_attention_heads"],
-                   kv_heads=m["num_key_value_heads"],
-                   head_dim=m.get("head_dim", m["hidden_size"]
-                                  // m["num_attention_heads"]),
-                   ffn=m["intermediate_size"], vocab=m["vocab_size"],
-                   qkv_bias=bool(m.get("attention_bias", False)),
-                   rope_theta=float(m["rope_theta"]),
-                   eps=float(m["rms_norm_eps"]), dtype=conf["dtype"])
-
-
-#: (leaf, shape, scale) of one layer; the scale is the draw's standard
-#: deviation, or "norm" for a norm weight (1 +- a tenth)
-def layer_leaves(m: Model) -> list:
-    d, h, hk, dh, f = m.d, m.heads, m.kv_heads, m.head_dim, m.ffn
-    out = [("ln1", (d,), "norm"),
-           ("wq", (d, h, dh), d ** -0.5),
-           ("wk", (d, hk, dh), d ** -0.5),
-           ("wv", (d, hk, dh), d ** -0.5),
-           ("wo", (h, dh, d), (h * dh) ** -0.5),
-           ("ln2", (d,), "norm"),
-           ("w_gate", (d, f), d ** -0.5),
-           ("w_up", (d, f), d ** -0.5),
-           ("w_down", (f, d), f ** -0.5)]
-    if m.qkv_bias:
-        out += [("bq", (h, dh), 0.5), ("bk", (hk, dh), 0.5),
-                ("bv", (hk, dh), 0.5)]
-    return out
-
-
-def top_leaves(m: Model) -> list:
-    return [("embed", (m.vocab, m.d), 1.0),
-            ("final_norm", (m.d,), "norm"),
-            ("lm_head", (m.d, m.vocab), m.d ** -0.5)]
 
 
 def key_data(seed: int) -> np.ndarray:
@@ -89,20 +40,42 @@ def _draw(key, shape, scale, dtype):
 
 def _leaves(kd, index, leaves, dtype):
     key = jax.random.fold_in(jax.random.wrap_key_data(kd), index)
-    return {name: _draw(jax.random.fold_in(key, i), shape, scale, dtype)
-            for i, (name, shape, scale) in enumerate(leaves)}
+    return {name: _draw(jax.random.fold_in(key, i), shape, scale,
+                        own[0] if own else dtype)
+            for i, (name, shape, scale, *own) in enumerate(leaves)}
 
 
-def layer(m: Model, kd, i, dtype=None) -> dict:
-    """Layer ``i``'s leaves (traceable in ``i``)."""
-    return _leaves(kd, i + 1, layer_leaves(m), dtype or m.dtype)
+def kinds(m) -> list:
+    """Each layer's kind: ``m.pattern`` cycled over the layers."""
+    return [m.pattern[i % len(m.pattern)] for i in range(m.layers)]
 
 
-def top(m: Model, kd, dtype=None) -> dict:
-    return _leaves(kd, 0, top_leaves(m), dtype or m.dtype)
+def split(m) -> tuple:
+    """(repeats, unit length, tail layers), as ``ModelConfig.scan_split``:
+    the pattern's unit repeats while whole, the rest is the tail."""
+    unit = len(m.pattern)
+    return m.layers // unit, unit, m.layers % unit
 
 
-def stacked(m: Model, kd) -> tuple:
-    """(top leaves, every layer's leaves stacked on a leading axis)."""
-    layers = jax.vmap(lambda i: layer(m, kd, i))(jnp.arange(m.layers))
-    return top(m, kd), layers
+def layer(m, kd, i, kind) -> dict:
+    """Layer ``i``'s leaves (traceable in ``i``), of the given kind."""
+    return _leaves(kd, i + 1, m.layer_leaves(kind), m.dtype)
+
+
+def top(m, kd) -> dict:
+    return _leaves(kd, 0, m.top_leaves(), m.dtype)
+
+
+def stacked(m, kd) -> tuple:
+    """(top leaves, {"scan": each unit position's leaves stacked over the
+    repeats, "tail": each tail layer's leaves}), keyed by position."""
+    n_rep, unit, n_tail = split(m)
+    kind = kinds(m)
+
+    def position(j):
+        return jax.vmap(lambda r: layer(m, kd, r * unit + j, kind[j]))(
+            jnp.arange(n_rep))
+    scan = {str(j): position(j) for j in range(unit)} if n_rep else {}
+    tail = {str(t): layer(m, kd, i, kind[i])
+            for t, i in enumerate(range(n_rep * unit, m.layers))}
+    return top(m, kd), {"scan": scan, "tail": tail}
