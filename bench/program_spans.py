@@ -1,11 +1,16 @@
-"""The serving program's own spans in a traced run of a cell.
+"""What the serving program's own spans say about a traced window.
+
+The trace reduction keeps the host events named ``serve.*`` that the
+program writes (``repro.serve.spans``, with their stats) as
+``Trace.program_spans``; the functions here read them, and the readers
+``bench/metrics/queue_wait_p90_s.py``, ``admit_p90_ms.py`` and
+``round_idle_ms.py`` and the ``idle_by_span`` of a traced run's
+``breakdown`` call them.
 
     python3 bench/program_spans.py --workload <cell> --seed <n> --seconds <s>
 
-runs the cell as ``bench/run.py --trace 1`` does, keeps the host events
-named ``serve.*`` that the program writes into the trace
-(``repro.serve.spans``, with their stats), and prints one more JSON
-line after the run's own: what those spans say about the window.
+runs the cell as ``bench/run.py --trace 1`` does and prints one more
+JSON line after the run's own, from those spans:
 
 - ``queue_wait_p90_s``: over the ``serve.submit`` spans that end in the
   window, the start of the ``serve.admit`` with the same ``rid`` less
@@ -36,46 +41,22 @@ line after the run's own: what those spans say about the window.
   (``run.Recorder``): requests queued, slots decoded, prompt tokens
   admitted, pages mapped from the prefix index and tokens handed out.
 
-Nothing here is read by ``bench/run.py`` or by a metric of
-``BENCHMARK.json``; a trace without ``serve.*`` spans gives no number.
+A trace without ``serve.*`` spans gives no number.
 """
 
 from __future__ import annotations
 
 import bisect
-import dataclasses
 import json
 import math
 import os
 import sys
 
+from trace import PROGRAM_PREFIX as PREFIX, merged
+
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
-PREFIX = "serve."
 NO_SPAN = "no span"
-
-
-@dataclasses.dataclass
-class Span:
-    name: str
-    start: float          # ns on the trace's clock
-    end: float
-    stats: dict
-
-
-def spans_of(pd) -> list:
-    """The ``serve.*`` host events of a ``ProfileData``, by start."""
-    out = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(PREFIX):
-                    out.append(Span(ev.name, ev.start_ns,
-                                    ev.start_ns + ev.duration_ns,
-                                    dict(ev.stats)))
-    return sorted(out, key=lambda s: (s.start, -s.end))
 
 
 def ending_in(spans, window, name=None) -> list:
@@ -118,7 +99,6 @@ def nearest_rank(values, q: float):
 
 def idle_intervals(tr, dev: int) -> list:
     """(start, end) of each idle interval of one device in the window."""
-    from trace import merged
     edges = [tr.window[0]] + [x for ab in merged(tr.ops[dev]) for x in ab] \
         + [tr.window[1]]
     return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -293,33 +273,15 @@ def report(tr, spans) -> dict:
             "self_s": self_seconds(spans, w)}
 
 
-def run_traced(run_mod, args, bench: dict):
-    """``run_mod.run_cell`` with a trace, keeping the program's spans.
-    Returns (result, the run's state, spans)."""
-    import trace as T
-    from jax.profiler import ProfileData
-    kept = {}
-
-    def load(path):
-        pd = ProfileData.from_file(path)
-        kept["spans"] = spans_of(pd)
-        return T.reduce(pd)
-    args.trace = 1
-    orig, T.load = T.load, load
-    try:
-        result, state = run_mod.run_cell(args, bench)
-    finally:
-        T.load = orig
-    return result, state, kept["spans"]
-
-
 def main(argv=None) -> int:
     sys.path.insert(0, BENCH)
     sys.path.insert(1, os.path.join(ROOT, "src"))
     import run as R
     args = R.parse(argv)
     bench = R.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    result, state, spans = run_traced(R, args, bench)
+    args.trace = 1
+    _, state = R.run_cell(args, bench)
+    spans = state.trace.program_spans
     rep = report(state.trace, spans)
     rep["late_p50_s"] = nearest_rank(lateness(
         spans, state.trace.window,

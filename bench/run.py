@@ -440,10 +440,13 @@ def run_cell(args, bench: dict, out=sys.stdout, err=sys.stderr) -> dict:
               "failed": check["unfinished_requests"]["value"],
               "metrics": metrics, "device": device}
     if args.trace:
+        import program_spans as P
         device["busy_s"] = run.trace.mean_busy_s()
         device["window_s"] = run.trace.window_s
         result["breakdown"] = {"device_ops": run.trace.top_ops(),
-                               "idle_gaps": run.trace.idle_gaps()}
+                               "idle_gaps": run.trace.idle_gaps(),
+                               "idle_by_span": P.idle_by_span(
+                                   run.trace, run.trace.program_spans)}
     result["check"] = check
     slowest = max((r.dur for r in run.rounds), default=0.0)
     print(f"bench: {cell['name']} seed={args.seed} rounds={in_window} "

@@ -93,21 +93,62 @@ def test_toy_cell_runs_and_is_correct(toy_copy):
     assert res["correct"] is True, err
     assert res["attempted"] > 0 and res["failed"] == 0
     assert state.m.program_config("toy").scan_split() == (2, 2, 1)
-    # every file the benchmark had is as it was, and BENCHMARK.json only
-    # gained entries
+    assert_only_added(toy_copy)
+
+
+def assert_only_added(root) -> None:
+    """Every file the benchmark had is as it was in the copy at
+    ``root``, and its BENCHMARK.json only gained entries."""
     old = [os.path.relpath(os.path.join(d, f), BENCH)
            for d, dirs, files in os.walk(BENCH)
            if "tests" not in d and "__pycache__" not in d for f in files]
     assert old and all(filecmp.cmp(os.path.join(BENCH, p),
-                                   os.path.join(toy_copy, "bench", p),
+                                   os.path.join(root, "bench", p),
                                    shallow=False) for p in old)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         before = json.load(f)
-    with open(os.path.join(toy_copy, "BENCHMARK.json")) as f:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         after = json.load(f)
     for key, was in before.items():
         now = after[key]
         assert (now[:len(was)] if isinstance(was, list) else now) == was
+
+
+SLOTS_READER = '''"""Slots decoded per round of the window, mean: the ``slots`` stat
+of the ``serve.dispatch`` spans inside each ``serve.round``."""
+
+import program_spans as P
+
+
+def read(run):
+    rounds = P.per_round(run.trace.program_spans, run.trace.window)
+    return sum(r["slots"] for r in rounds) / len(rounds) if rounds else None
+'''
+
+
+def test_a_reader_of_a_program_stat_joins_as_new_files(toy_copy):
+    """A per-layer metric over a stat of the program's spans joins the
+    toy's cell as a new reader file and a new entry only, and a traced
+    run reads it equal to the harness's own count."""
+    with open(toy_copy / "bench" / "metrics" / "dispatch_slots_mean.py",
+              "w") as f:
+        f.write(SLOTS_READER)
+    with open(toy_copy / "BENCHMARK.json") as f:
+        spec = json.load(f)
+    spec["per_layer"].append({"name": "dispatch_slots_mean",
+                              "unit": "slots", "better": "higher",
+                              "source": "program_span",
+                              "layer": "engine and scheduler",
+                              "moves": "tpot_p90_ms",
+                              "workloads": ["toy.chat"]})
+    write_json(toy_copy / "BENCHMARK.json", spec)
+    res, state, _, err = run_once(toy_copy, "toy.chat", SEED, 3.0, trace=1)
+    assert res["correct"] is True, err
+    slots = [r.slots for r in state.rounds]
+    assert len(slots) > 0 and max(slots) > 1
+    assert res["metrics"]["dispatch_slots_mean"] == {
+        "value": sum(slots) / len(slots), "unit": "slots"}
+    assert_only_added(toy_copy)
 
 
 def test_toy_without_its_convolution_reads_not_correct(tmp_path):

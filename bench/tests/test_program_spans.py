@@ -1,17 +1,20 @@
-"""What ``program_spans.py`` reads from the program's ``serve.*`` spans:
-exact values on a small hand-made trace, nothing on a trace that has no
-such spans, and, in a traced CPU run of an added cell, span stats that
-reproduce the harness's own counts round by round."""
+"""What ``program_spans.py`` and the readers over it read from the
+program's ``serve.*`` spans (``Trace.program_spans``): exact values on a
+small hand-made trace, nothing on a trace that has no such spans, and,
+in a traced CPU run of an added cell, span stats that reproduce the
+harness's own counts round by round."""
 
-import io
+import hashlib
 import json
 import os
+import types
 
 import pytest
 
 import program_spans as P
+import run
 import trace as T
-from conftest import load_run
+from test_run_smoke import run_once
 
 US = 1_000_000       # picoseconds in a microsecond
 
@@ -77,11 +80,21 @@ def textproto(host=HOST, ops=OPS) -> str:
     return dev + hst
 
 
+#: sha256 of the excerpt's ``top_ops()`` as JSON, whole names included
+TOP_SHA = "472709d04939d737308dd7f70e7b6fd63232d8da8637472fec08cc2eff4602da"
+#: the readers of BENCHMARK.json over the program's spans
+READERS = ("queue_wait_p90_s", "admit_p90_ms", "round_idle_ms")
+
+
+def reduced(host=HOST):
+    from jax.profiler import ProfileData
+    return T.reduce(ProfileData.from_text_proto(textproto(host=host)))
+
+
 @pytest.fixture(scope="module")
 def made():
-    from jax.profiler import ProfileData
-    pd = ProfileData.from_text_proto(textproto())
-    return T.reduce(pd), P.spans_of(pd)
+    tr = reduced()
+    return tr, tr.program_spans
 
 
 def test_spans_keep_their_stats(made):
@@ -158,13 +171,10 @@ def test_self_time_and_rounds(made):
          "emitted": 9}]
 
 
-def test_nothing_to_read_without_program_spans(made):
-    from jax.profiler import ProfileData
-    host = [h for h in HOST if not h[0].startswith("serve.")]
-    pd = ProfileData.from_text_proto(textproto(host=host))
-    tr = T.reduce(pd)
-    assert P.spans_of(pd) == []
-    rep = P.report(tr, [])
+def test_nothing_to_read_without_program_spans():
+    tr = reduced([h for h in HOST if not h[0].startswith("serve.")])
+    assert tr.program_spans == []
+    rep = P.report(tr, tr.program_spans)
     assert rep["queue_wait_p90_s"] is None and rep["admit_p90_ms"] is None
     assert rep["round_idle_ms"] is None
     # the device's idle time then goes to the harness's spans
@@ -172,33 +182,66 @@ def test_nothing_to_read_without_program_spans(made):
         "no span", "bench.wait", "bench.submit", "bench.step"}
 
 
+@pytest.mark.parametrize("name", READERS)
+def test_reader_reads_what_report_prints(made, name):
+    tr, spans = made
+    got = run.reader(name)(types.SimpleNamespace(trace=tr))
+    assert got == P.report(tr, spans)[name]
+    assert got == pytest.approx({"queue_wait_p90_s": 485e-6,
+                                 "admit_p90_ms": 0.09,
+                                 "round_idle_ms": 0.06}[name])
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_reader_reads_nothing_without_program_spans(name):
+    tr = reduced([h for h in HOST if not h[0].startswith("serve.")])
+    assert tr.n_devices == 1
+    assert run.reader(name)(types.SimpleNamespace(trace=tr)) is None
+
+
 def test_the_harness_reduction_is_unchanged():
-    """The loader that keeps the spans hands the harness the same
-    reduction of the recorded excerpt as its own loader does."""
+    """The recorded excerpt reduces to what the harness read before the
+    reduction kept the program's spans: window, its own spans, the
+    costliest ops and the longest idle gaps, pinned exactly."""
     from jax.profiler import ProfileData
     data = os.path.join(os.path.dirname(__file__), "data",
                         "paged_step_excerpt.textproto")
     with open(data) as f:
-        pd = ProfileData.from_text_proto(f.read())
-    a, b = T.reduce(pd), T.reduce(pd)
-    assert P.spans_of(pd) == []
-    assert (a.window, a.spans, a.top_ops(), a.idle_gaps()) \
-        == (b.window, b.spans, b.top_ops(), b.idle_gaps())
+        tr = T.reduce(ProfileData.from_text_proto(f.read()))
+    assert tr.program_spans == []
+    assert tr.window == (417594404.0, 447594404.0)
+    assert [(s.name, s.start, s.end, s.stats) for s in tr.spans] == [
+        ("bench.window", 417594404.0, 447594404.0, {}),
+        ("bench.window", 49934706.0, 8262038377.0, {}),
+        ("bench.step", 334092720.0, 649961301.0, {})]
+    top = tr.top_ops()
+    assert [[k.split(" = ")[0], v] for k, v in top] == [
+        ["jit_paged_step/%copy.90", 0.00339171],
+        ["jit_paged_step/%bitcast_add_fusion.3", 0.0029138940000000006],
+        ["jit_paged_step/%fusion.157", 0.0028951150000000006],
+        ["jit_paged_step/%fusion.158", 0.002894521999999999],
+        ["jit_paged_step/%checkpoint.10", 0.0027993709999999997],
+        ["jit_paged_step/%copy.29", 0.002436296],
+        ["jit_paged_step/%bitcast_dynamic-update-slice_fusion.4",
+         0.0015832119999999996],
+        ["jit_paged_step/%bitcast_dynamic-update-slice_fusion.5",
+         0.0015828300000000001],
+        ["jit_paged_step/%dynamic-slice_bitcast_fusion.5",
+         0.0015322860000000003],
+        ["jit_paged_step/%dynamic-slice_bitcast_fusion.4", 0.001529817]]
+    assert hashlib.sha256(json.dumps(top).encode()).hexdigest() == TOP_SHA
+    assert tr.idle_gaps() == [
+        ["bench.step", 0.001612394], ["bench.step", 0.00038730700000000004]
+    ] + [["bench.step", 2e-09]] + [["bench.step", 1e-09]] * 6
 
 
 def test_traced_run_reproduces_the_recorder(bench_copy):
     """A traced CPU run of the added cell: the program's span stats
     give the harness's own per-round counts, round for round."""
-    run = load_run(bench_copy)
-    args = run.parse(["--workload", "smoke.chat", "--seed",
-                      str(2**31 + 11), "--seconds", "3.0", "--trace", "0"])
-    with open(os.path.join(bench_copy, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    out = io.StringIO()
-    run_cell = run.run_cell
-    run.run_cell = lambda a, b: run_cell(a, b, out=out, err=io.StringIO())
-    result, state, spans = P.run_traced(run, args, bench)
-    assert json.loads(out.getvalue().splitlines()[-1]) == result
+    res, state, out, _ = run_once(bench_copy, "smoke.chat", 2**31 + 11,
+                                  3.0, trace=1)
+    assert json.loads(out.splitlines()[-1]) == res
+    spans = state.trace.program_spans
     got = P.per_round(spans, state.trace.window)
     want = P.recorder_rows(state.rounds)
     assert len(got) == len(want) > 0
@@ -209,6 +252,7 @@ def test_traced_run_reproduces_the_recorder(bench_copy):
     assert rep["queue_wait_p90_s"] >= 0 and rep["admit_p90_ms"] > 0
     # the CPU has no device planes: what needs them stays silent
     assert rep["round_idle_ms"] is None and rep["idle_by_span"] == []
+    assert res["breakdown"]["idle_by_span"] == []
     late = P.lateness(spans, state.trace.window,
                       {rid: life.due for rid, life in state.lives.items()})
     assert len(late) == rep["requests_submitted"] > 0

@@ -1,7 +1,8 @@
 """The trace reduction on a small recorded trace: 30 ms of a TPU v5e
 trace of the yi-9b-24l cell (one paged decode program running, cut out
 of a recorded ``.xplane.pb`` into ``data/paged_step_excerpt.textproto``
-with the benchmark's window span set around it)."""
+with the benchmark's window span set around it); and, on a hand-made
+trace, the program's own spans that it keeps beside the harness's."""
 
 import os
 
@@ -65,3 +66,23 @@ def test_merged_intervals():
     ev = [T.Event("a", 0, 5), T.Event("b", 3, 8), T.Event("c", 10, 12),
           T.Event("d", 11, 11.5)]
     assert T.merged(ev) == [(0, 8), (10, 12)]
+
+
+def test_reduce_keeps_the_program_spans_whole_with_their_stats():
+    from jax.profiler import ProfileData
+    from test_program_spans import HOST, textproto
+    # a last round that runs past the window's close (0-1000 us)
+    host = HOST + [("serve.round", 980, 1100, {"queued": 0, "active": 1}),
+                   ("serve.decode", 980, 1090, {"emitted": 8,
+                                                "retired": 1})]
+    tr = T.reduce(ProfileData.from_text_proto(textproto(host=host)))
+    want = sorted([(n, a * 1e3, b * 1e3, st) for n, a, b, st in host
+                   if n.startswith("serve.")], key=lambda x: (x[1], -x[2]))
+    assert [(e.name, e.start, e.end, e.stats)
+            for e in tr.program_spans] == want
+    assert tr.program_spans[-2].end == 1_100_000 > tr.window[1]
+    assert tr.program_spans[0].stats == {"rid": "r1", "replica": 0}
+    # the harness's own spans are as they were: bench.* only, no stats
+    assert [(e.name, e.start, e.end, e.stats) for e in tr.spans] == [
+        (n, a * 1e3, b * 1e3, {}) for n, a, b, _ in host
+        if n.startswith("bench.")]

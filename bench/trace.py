@@ -8,9 +8,11 @@ function (``jit_paged_step(<hash>)``). An op's program is the module
 event that contains it. The host plane holds the benchmark's own spans
 (``jax.profiler.TraceAnnotation``, names starting ``bench.``); the span
 ``bench.window`` marks the measured window, and every device reading is
-clipped to it. Control-flow ops (``%while`` and the like) span the ops
-of their bodies, so they count towards busy time but are left out of
-the per-op list.
+clipped to it. It also holds the serving program's own spans (names
+starting ``serve.``, ``repro.serve.spans``), kept whole with their stats
+as ``Trace.program_spans`` for the readers of ``program_spans.py``.
+Control-flow ops (``%while`` and the like) span the ops of their bodies,
+so they count towards busy time but are left out of the per-op list.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ import numpy as np
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "serve."
 WINDOW_SPAN = "bench.window"
 #: ops whose interval holds other ops' intervals
 CONTROL_OPS = ("%while", "%conditional", "%call")
@@ -36,6 +39,7 @@ class Event:
     start: float          # ns on the trace's clock
     end: float
     module: str = ""      # the program (module event) it ran in
+    stats: dict = dataclasses.field(default_factory=dict)
 
     @property
     def short(self) -> str:
@@ -49,6 +53,8 @@ class Trace:
     ops: list                        # per device: [Event] in the window
     modules: list                    # per device: [Event] in the window
     spans: list                      # host [Event] named bench.*
+    #: host [Event] named serve.*, with stats, by (start, -end), unclipped
+    program_spans: list
 
     @property
     def window_s(self) -> float:
@@ -159,7 +165,7 @@ def load(path: str) -> Trace:
 
 def reduce(pd) -> Trace:
     """The window's device events and host spans of a ``ProfileData``."""
-    spans, ops, modules = [], [], []
+    spans, program, ops, modules = [], [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -167,6 +173,10 @@ def reduce(pd) -> Trace:
                     if ev.name.startswith(SPAN_PREFIX):
                         spans.append(Event(ev.name, ev.start_ns,
                                            ev.start_ns + ev.duration_ns))
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        program.append(Event(ev.name, ev.start_ns,
+                                             ev.start_ns + ev.duration_ns,
+                                             stats=dict(ev.stats)))
         elif plane.name.startswith(DEVICE_PREFIX) \
                 and plane.name[len(DEVICE_PREFIX):].isdigit():
             o, m = [], []
@@ -186,4 +196,6 @@ def reduce(pd) -> Trace:
         raise ValueError(f"trace has no {WINDOW_SPAN} span")
     lo, hi = win[0].start, win[0].end
     return Trace(window=(lo, hi), ops=[_clip(o, lo, hi) for o in ops],
-                 modules=[_clip(m, lo, hi) for m in modules], spans=spans)
+                 modules=[_clip(m, lo, hi) for m in modules], spans=spans,
+                 program_spans=sorted(program,
+                                      key=lambda s: (s.start, -s.end)))
